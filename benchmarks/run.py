@@ -38,7 +38,6 @@ def _force_cpu_grid() -> None:
 
 
 def run_json(out_dir: pathlib.Path) -> None:
-    _force_cpu_grid()
     from benchmarks import attention_bench, roofline_bench, serve_bench
 
     serve_json = serve_bench.run_grid()
@@ -99,6 +98,10 @@ def main() -> None:
     ap.add_argument("--out-dir", default=str(pathlib.Path(__file__).parent),
                     help="where --json writes the BENCH_*.json files")
     args = ap.parse_args()
+    if args.json:
+        _force_cpu_grid()
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     if args.json:
         run_json(pathlib.Path(args.out_dir))

@@ -100,17 +100,6 @@ def exp_lookup(z_q: jax.Array, exp_lut: jax.Array) -> jax.Array:
     return jnp.take(exp_lut, idx, axis=0)
 
 
-def exp_lookup_onehot(z_q: jax.Array, exp_lut: jax.Array) -> jax.Array:
-    """MXU-friendly LUT read: one-hot(z_q) @ table.
-
-    Pallas TPU kernels prefer a (tile, 256) x (256,) matmul over a gather;
-    numerically identical to :func:`exp_lookup` (the one-hot is exact).
-    """
-    idx = z_q.astype(jnp.int32) + 128
-    onehot = jax.nn.one_hot(idx, 256, dtype=jnp.float32)
-    return (onehot @ exp_lut.astype(jnp.float32)).astype(jnp.int32)
-
-
 def recip_mantissa_index(s: jax.Array, mbits: int
                          ) -> Tuple[jax.Array, jax.Array]:
     """Exact exponent/mantissa split of a positive value, via IEEE-754 bits.

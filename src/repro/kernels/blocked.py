@@ -29,6 +29,11 @@ from repro.core import lut as lut_lib
 from repro.core import quantization as qlib
 from repro.core.lut import LUTConfig, Z_QUANT_MAX
 
+# The int8 path's e.V dots carry exp-LUT values up to 2^15, which bf16 cannot
+# hold exactly; TPU's default f32 dot rounds operands to bf16, HIGHEST does
+# not.  (The QK^T dots are int8 x int8 -> int32 and exact anyway.)
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def _chunk_mask(sq: int, bk: int, base: jax.Array, *, causal: bool,
                 window: Optional[int], kv_valid_len: Optional[jax.Array],
@@ -57,18 +62,26 @@ def blocked_splitmax_attention(
     block_k: int = 512,
     exact_recip: bool = False,
 ) -> jax.Array:
-    """int8 split-softmax attention as a k-chunk scan.  Shapes as ref.py."""
+    """int8 split-softmax attention as a k-chunk scan.  Shapes as ref.py.
+
+    A ragged Sk is zero-padded to whole chunks; the padded keys sit past
+    ``kv_valid_len`` (defaulted to Sk) and are masked.
+    """
     b, hq, sq, d = q_q.shape
     _, hkv, sk, _ = k_q.shape
     g = hq // hkv
     block_k = min(block_k, sk)
-    assert sk % block_k == 0, (sk, block_k)
-    nk = sk // block_k
+    nk = -(-sk // block_k)
+    if nk * block_k != sk:
+        pad = ((0, 0), (0, 0), (0, nk * block_k - sk), (0, 0))
+        k_q, v_q = jnp.pad(k_q, pad), jnp.pad(v_q, pad)
+        if kv_valid_len is None:
+            kv_valid_len = jnp.int32(sk)
 
     m_z = (s_q * s_k / (jnp.sqrt(jnp.float32(d)) * cfg.scale_z)
            ).astype(jnp.float32)
     # grouped view avoids materializing GQA-repeated K/V
-    qg = q_q.reshape(b, hkv, g, sq, d).astype(jnp.int32)
+    qg = q_q.reshape(b, hkv, g, sq, d)
     ks = jnp.moveaxis(k_q.reshape(b, hkv, nk, block_k, d), 2, 0)
     vs = jnp.moveaxis(v_q.reshape(b, hkv, nk, block_k, d), 2, 0)
 
@@ -76,13 +89,15 @@ def blocked_splitmax_attention(
         acc, s = carry
         idx, kc, vc = xs
         base = idx * block_k
-        z32 = jnp.einsum("bkgqd,bkcd->bkgqc", qg, kc.astype(jnp.int32))
+        z32 = jnp.einsum("bkgqd,bkcd->bkgqc", qg, kc,
+                         preferred_element_type=jnp.int32)
         z_q = qlib.requantize_int32(z32, m_z)
         e = lut_lib.exp_lookup(z_q, exp_lut).astype(jnp.float32)
         mask = _chunk_mask(sq, block_k, base, causal=causal, window=window,
                            kv_valid_len=kv_valid_len)
         e = jnp.where(mask[None, None, None], e, 0.0)
-        acc = acc + jnp.einsum("bkgqc,bkcd->bkgqd", e, vc.astype(jnp.float32))
+        acc = acc + jnp.einsum("bkgqc,bkcd->bkgqd", e, vc.astype(jnp.float32),
+                               precision=_EXACT)
         s = s + jnp.sum(e, axis=-1)
         return (acc, s), None
 
@@ -121,8 +136,9 @@ def grouped_splitmax_decode(
     g = hq // hkv
     m_z = (s_q * s_k / (jnp.sqrt(jnp.float32(d)) * cfg.scale_z)
            ).astype(jnp.float32)
-    qg = q_q.reshape(b, hkv, g, d).astype(jnp.int32)
-    z32 = jnp.einsum("bkgd,bksd->bkgs", qg, k_cache.astype(jnp.int32))
+    qg = q_q.reshape(b, hkv, g, d)
+    z32 = jnp.einsum("bkgd,bksd->bkgs", qg, k_cache,
+                     preferred_element_type=jnp.int32)
     z_q = qlib.requantize_int32(z32, m_z)
     e = lut_lib.exp_lookup(z_q, exp_lut).astype(jnp.float32)
     kpos = jnp.arange(s_max)[None, :]
@@ -130,7 +146,8 @@ def grouped_splitmax_decode(
     if window is not None:
         valid &= kpos > cache_len[:, None] - 1 - window
     e = jnp.where(valid[:, None, None, :], e, 0.0)
-    acc = jnp.einsum("bkgs,bksd->bkgd", e, v_cache.astype(jnp.float32))
+    acc = jnp.einsum("bkgs,bksd->bkgd", e, v_cache.astype(jnp.float32),
+                     precision=_EXACT)
     s = jnp.maximum(jnp.sum(e, axis=-1), 1.0)[..., None]
     if exact_recip:
         out = acc / s

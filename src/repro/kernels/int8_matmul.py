@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 
 def _int8_matmul_kernel(scalars_ref, x_ref, w_ref, out_ref, acc_ref, *,
                         num_k_blocks: int, requant: bool):
@@ -34,8 +32,7 @@ def _int8_matmul_kernel(scalars_ref, x_ref, w_ref, out_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jax.lax.dot_general(
-        x_ref[...].astype(jnp.int32), w_ref[...].astype(jnp.int32),
-        (((1,), (0,)), ((), ())),
+        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)
 
     @pl.when(ki == num_k_blocks - 1)
@@ -89,7 +86,7 @@ def int8_matmul_pallas(
                           num_k_blocks=k // block_k, requant=requant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(scalars, x_q, w_q)

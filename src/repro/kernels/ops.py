@@ -29,6 +29,7 @@ from repro.kernels import autotune
 from repro.kernels import blocked as blocked_lib
 from repro.kernels import ref as ref_lib
 from repro.kernels.int8_matmul import int8_matmul_pallas
+from repro.kernels.pallas_compat import pallas_supported
 from repro.kernels.splitmax_attn import splitmax_attention_pallas
 from repro.kernels.splitmax_decode import (
     splitmax_decode_fused_paged_pallas, splitmax_decode_fused_pallas,
@@ -37,13 +38,9 @@ from repro.kernels.splitmax_decode import (
     splitmax_decode_pallas)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _resolve(impl: str) -> str:
     if impl == "auto":
-        return "pallas" if _on_tpu() else "xla"
+        return "pallas" if pallas_supported() else "xla"
     return impl
 
 

@@ -1,34 +1,13 @@
-"""JAX-version / backend compatibility for the Pallas TPU kernels.
+"""Backend capability gate for the Pallas TPU kernels.
 
-The TPU compiler-params dataclass was renamed across JAX releases:
-``pltpu.TPUCompilerParams`` (0.4.x) became ``pltpu.CompilerParams`` (newer
-releases, which keep the old name only as a deprecated alias for a while).
-The kernels call :func:`tpu_compiler_params` instead of either name so one
-source tree runs against both generations of the toolchain.
-
-:func:`pallas_supported` is the single capability gate the dispatch layer
+:func:`pallas_supported` is the single check the dispatch layer
 (`kernels/ops.py`) and the autotuner (`kernels/autotune.py`) consult before
-reaching for a *compiled* Pallas kernel — everywhere else falls back to the
-interpreter or the XLA twin of the same math.
+reaching for a *compiled* Pallas kernel — everywhere else the kernels run in
+the interpreter (tests) or are replaced by the XLA twin of the same math.
 """
 from __future__ import annotations
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-# Prefer the new name so the deprecated alias (when both exist) is never
-# touched; fall back to the 0.4.x name.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
-
-def tpu_compiler_params(**kwargs):
-    """Build the TPU compiler-params object for ``pl.pallas_call``.
-
-    Keyword arguments (``dimension_semantics=...`` etc.) pass through
-    unchanged — the dataclass fields kept their names across the rename.
-    """
-    return _COMPILER_PARAMS_CLS(**kwargs)
 
 
 def pallas_supported() -> bool:
@@ -36,7 +15,8 @@ def pallas_supported() -> bool:
 
     Mosaic lowering of these kernels targets TPU; on CPU/GPU backends the
     kernels are exercised through ``interpret=True`` (tests) or replaced by
-    the blocked XLA twins (production fallbacks).  Autotune sweeps use this
-    to decide whether timing the compiled kernel is meaningful.
+    the blocked XLA twins.  ``impl="auto"`` resolves to Pallas exactly when
+    this is true, and autotune sweeps use it to decide whether timing the
+    compiled kernel is meaningful.
     """
     return jax.default_backend() == "tpu"

@@ -92,8 +92,7 @@ def splitmax_attention_ref(
     k_q = _expand_gqa(k_q, hq)
     v_q = _expand_gqa(v_q, hq)
     sk = k_q.shape[2]
-    assert sk % block_k == 0, (sk, block_k)
-    n_tiles = sk // block_k
+    n_tiles = -(-sk // block_k)
 
     m_z = (s_q * s_k / (jnp.sqrt(jnp.float32(d)) * cfg.scale_z)).astype(
         jnp.float32)
@@ -112,13 +111,18 @@ def splitmax_attention_ref(
         full_mask = full_mask & mask
     e = jnp.where(full_mask, e, 0)
 
-    # 4: split accumulation, tiled like the kernel
-    e_t = e.reshape(b, hq, sq, n_tiles, block_k)
+    # 4: split accumulation, tiled like the kernel (a ragged last tile is
+    # zero-padded, as the kernel pads its inputs)
+    e_t = jnp.pad(e, ((0, 0),) * 3 + ((0, n_tiles * block_k - sk),))
+    e_t = e_t.reshape(b, hq, sq, n_tiles, block_k)
     s_tile = jnp.sum(e_t, axis=-1, dtype=jnp.int32)          # exact per tile
     acc_s = jnp.sum(s_tile.astype(jnp.float32), axis=-1)     # f32 across tiles
+    # e <= 2^15 is not bf16-exact: HIGHEST keeps the f32 dot exact on TPU,
+    # whose default f32 precision rounds operands to bf16
     acc_v = jax.lax.dot_general(
         e.astype(jnp.float32), v_q.astype(jnp.float32),
-        (((3,), (2,)), ((0, 1), (0, 1))))                    # (B,Hq,Sq,D)
+        (((3,), (2,)), ((0, 1), (0, 1))),
+        precision=jax.lax.Precision.HIGHEST)                 # (B,Hq,Sq,D)
 
     # 5: reciprocal
     acc_s = jnp.maximum(acc_s, 1.0)[..., None]

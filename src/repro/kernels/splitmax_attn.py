@@ -6,7 +6,7 @@ streaming attention*: because the scores entering softmax are int8-quantized,
 (FlashAttention's online renormalization) is needed.  The kernel therefore
 streams K/V tiles HBM->VMEM once, accumulating
 
-    acc_v += ExpLUT[z_q] . V        (numerator, int->f32 MXU matmul)
+    acc_v += ExpLUT[z_q] . V        (numerator, exact bf16->f32 MXU matmul)
     acc_s += sum_k ExpLUT[z_q]      (denominator, exact int32 per tile)
 
 and applies the reciprocal-LUT multiply exactly once per row at the last
@@ -18,14 +18,22 @@ Hardware mapping notes
 * The dual-banked "simultaneous read+write" of the CIM array corresponds to
   the automatic double-buffering of BlockSpec tiles (compute on tile i while
   tile i+1 DMAs in).
-* The exp LUT is read with a one-hot MXU matmul (``lut_mode='onehot'``, exact
-  w.r.t. the int8 table — bit-identical to ``jnp.take`` in the oracle) or
-  recomputed in f32 (``lut_mode='compute'``, cheaper, <=1 LSB deviation).
+* The exp LUT is read exactly (``lut_mode='onehot'``, bit-identical to
+  ``jnp.take`` in the oracle) as an in-register lane gather: the 256-entry
+  table sits on the lanes of two vregs and each 128-lane slice of the score
+  tile picks its entries with ``take_along_axis``.  ``lut_mode='compute'``
+  recomputes the entry in f32 instead (cheaper, <=1 LSB deviation).
+* Every matmul is exact on the MXU: QK^T takes int8 operands with int32
+  accumulation, and e.V splits the <=2^15 LUT values into two bf16-exact
+  halves (see :func:`_exact_pv`), so no f32 dot depends on the chip's
+  default matmul precision.
 * The 32b->8b quantization unit is fused into the tile epilogue (requant of
   the z accumulator before the LUT).
 
 Grid: (B*Hq, Sq/block_q, Sk/block_k), k innermost ("arbitrary"), carries in
-VMEM scratch.  Causally dead k-tiles are skipped with ``pl.when``.
+VMEM scratch.  Causally dead k-tiles are skipped with ``pl.when``.  Any
+Sq/Sk is accepted: the launcher pads to the tile and ``kv_valid_len`` masks
+the padded keys.
 """
 from __future__ import annotations
 
@@ -37,40 +45,87 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 from repro.core.lut import LUTConfig
 
 NEG_DOMAIN = 128  # index offset: z_q in [-128, 127] -> [0, 255]
+LANES = 128       # vreg lane width; a LUT of 256 entries spans two vregs
+TABLE_SUBLANES = 8
 
 
-def _onehot_lookup(idx: jax.Array, table_ref) -> jax.Array:
-    """Exact LUT read as a one-hot matmul (MXU-friendly).
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
-    idx: (rows, cols) int32 in [0, 256). table_ref: (256, 128) f32 ref whose
-    lanes replicate the table (lane-replicated layout keeps the matmul shape
-    TPU-native).  Returns (rows, cols) f32 of exact table values.
+
+def _lane_gather(table_ref, idx: jax.Array) -> jax.Array:
+    """(rows, 128) int32 indices in [0, 256) -> (rows, 128) f32 entries.
+
+    ``tpu.dynamic_gather`` gathers within one vreg along the lanes, so the
+    table is read as two 128-lane halves and the index's top bit selects.
+    """
+    rows = idx.shape[0]
+    lo = jnp.broadcast_to(table_ref[0:1, 0:LANES], (rows, LANES))
+    hi = jnp.broadcast_to(table_ref[0:1, LANES:2 * LANES], (rows, LANES))
+    low = jnp.bitwise_and(idx, LANES - 1)
+    return jnp.where(
+        idx >= LANES,
+        jnp.take_along_axis(hi, low, axis=1, mode="promise_in_bounds"),
+        jnp.take_along_axis(lo, low, axis=1, mode="promise_in_bounds"))
+
+
+def _table_lookup(idx: jax.Array, table_ref) -> jax.Array:
+    """Exact LUT read: (rows, cols) int32 in [0, 256) -> (rows, cols) f32.
+
+    ``table_ref`` is a (8, 256) f32 ref whose rows all hold the table (see
+    :func:`_replicate_table`).  The tile is gathered 128 lanes at a time; a
+    tile narrower than a multiple of 128 is padded with index 0 first.
     """
     rows, cols = idx.shape
-    flat = idx.reshape(rows * cols, 1)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (rows * cols, 256), 1)
-    onehot = (iota == flat).astype(jnp.float32)
-    vals = jax.lax.dot_general(
-        onehot, table_ref[:, :1],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return vals.reshape(rows, cols)
+    width = _round_up(cols, LANES)
+    if width != cols:
+        idx = jnp.concatenate(
+            [idx, jnp.zeros((rows, width - cols), jnp.int32)], axis=1)
+    parts = [_lane_gather(table_ref, idx[:, j:j + LANES])
+             for j in range(0, width, LANES)]
+    out = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+    return out[:, :cols] if width != cols else out
 
 
 def _recip_lut_inline(s_f32: jax.Array, recip_ref, cfg: LUTConfig) -> jax.Array:
     """Reciprocal-LUT approximation of 1/s — *identical* bit path to
     ``lut_lib.recip_lookup`` (IEEE-754 exponent/mantissa extraction; float
     log2/exp2 are an ulp off at bin boundaries and flip the index), with the
-    table read done as a one-hot matmul.  s_f32: (bq, 1) f32 > 0."""
+    table read done as a lane gather.  s_f32: (bq, 1) f32 > 0."""
     from repro.core import lut as lut_lib
     idx, expo = lut_lib.recip_mantissa_index(s_f32, cfg.recip_index_bits)
-    r = _onehot_lookup(idx, recip_ref)                     # (bq, 1)
+    rows = idx.shape[0]
+    r = _lane_gather(recip_ref, jnp.broadcast_to(idx, (rows, LANES)))[:, :1]
     return r * lut_lib.exp2_int(-expo - cfg.recip_frac_bits)
+
+
+def _qk_scores(q_i8: jax.Array, k_i8: jax.Array) -> jax.Array:
+    """The "CIM array": (R, D) x (C, D) int8 -> (R, C) int32 on the MXU."""
+    return jax.lax.dot_general(q_i8, k_i8, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.int32)
+
+
+def _exact_pv(e: jax.Array, v_i8: jax.Array) -> jax.Array:
+    """e . V with e (R, C) f32 integers in [0, 2^15] and V (C, D) int8.
+
+    bf16 holds every integer up to 256 exactly, so ``e = 256 * hi + lo``
+    with ``hi <= 128`` and ``lo < 256`` gives two bf16-exact operands.
+    Products and partial sums stay below 2^24 for C <= 512, so both f32
+    accumulations are exact in any order and the result does not depend on
+    the matmul precision the backend defaults to.
+    """
+    hi = jnp.floor(e * (1.0 / 256))
+    lo = e - hi * 256
+    v = v_i8.astype(jnp.float32).astype(jnp.bfloat16)
+
+    def dot(a):
+        return jax.lax.dot_general(
+            a.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    return dot(hi) * 256 + dot(lo)
 
 
 def _splitmax_kernel(
@@ -80,8 +135,8 @@ def _splitmax_kernel(
     q_ref,                  # (1, block_q, D) int8
     k_ref,                  # (1, block_k, D) int8
     v_ref,                  # (1, block_k, D) int8
-    exp_ref,                # (256, 128) f32 — exp LUT, lane-replicated
-    recip_ref,              # (256, 128) f32 — recip LUT, lane-replicated
+    exp_ref,                # (8, 256) f32 — exp LUT on the lanes
+    recip_ref,              # (8, 256) f32 — recip LUT on the lanes
     # outputs
     out_ref,                # (1, block_q, D) f32
     # scratch
@@ -125,18 +180,14 @@ def _splitmax_kernel(
 
     @pl.when(jnp.asarray(live))
     def _compute():
-        q = q_ref[0].astype(jnp.int32)                       # (bq, D)
-        k = k_ref[0].astype(jnp.int32)                       # (bk, D)
         # 1. the "CIM array": int8 MACs with int32 accumulation
-        z32 = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32)                # (bq, bk)
+        z32 = _qk_scores(q_ref[0], k_ref[0])                 # (bq, bk)
         # 2. 32b -> 8b quantization unit
         z_q = jnp.clip(jnp.round(z32.astype(jnp.float32) * m_z),
                        -128, 127).astype(jnp.int32)
         # 3. exp LUT
         if lut_mode == "onehot":
-            e = _onehot_lookup(z_q + NEG_DOMAIN, exp_ref)    # exact, f32 ints
+            e = _table_lookup(z_q + NEG_DOMAIN, exp_ref)     # exact, f32 ints
         else:  # "compute": arithmetic reconstruction, <=1 LSB off the table
             e = jnp.round(jnp.exp((z_q - 127).astype(jnp.float32)
                                   * cfg.scale_z)
@@ -153,10 +204,7 @@ def _splitmax_kernel(
             mask &= cols > rows - window
         e = jnp.where(mask, e, 0.0)
         # 5. split accumulation
-        acc_ref[...] += jax.lax.dot_general(
-            e, v_ref[0].astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (bq, D)
+        acc_ref[...] += _exact_pv(e, v_ref[0])               # (bq, D)
         s_ref[:, :1] += jnp.sum(e, axis=1, keepdims=True)
 
     @pl.when(ki == num_k_blocks - 1)
@@ -170,8 +218,12 @@ def _splitmax_kernel(
 
 
 def _replicate_table(t: jax.Array) -> jax.Array:
-    """(256,) int32 table -> (256, 128) f32, lane-replicated for VMEM."""
-    return jnp.broadcast_to(t.astype(jnp.float32)[:, None], (256, 128))
+    """(n <= 256,) int32 table -> (8, 256) f32 with the table on every row,
+    zero-padded to two full vregs of lanes for :func:`_lane_gather`."""
+    n = t.shape[0]
+    assert n <= 2 * LANES, n
+    row = jnp.pad(t.astype(jnp.float32), (0, 2 * LANES - n))
+    return jnp.broadcast_to(row[None, :], (TABLE_SUBLANES, 2 * LANES))
 
 
 @functools.partial(
@@ -197,17 +249,30 @@ def splitmax_attention_pallas(
     exact_recip: bool = False,
     interpret: bool = False,
 ) -> jax.Array:
-    """Returns (B, Hq, Sq, D) float32 attention output (dequantized)."""
+    """Returns (B, Hq, Sq, D) float32 attention output (dequantized).
+
+    Any Sq/Sk: tiles shrink to the (32-row aligned) sequence when it is
+    shorter than ``block_q``/``block_k``, the inputs are zero-padded to a
+    whole number of tiles, padded keys sit past ``kv_valid_len`` and are
+    masked, and padded query rows are sliced off the output.
+    """
     b, hq, sq, d = q_q.shape
     _, hkv, sk, _ = k_q.shape
     assert hq % hkv == 0, (hq, hkv)
     group = hq // hkv
-    assert sq % block_q == 0 and sk % block_k == 0, (sq, sk, block_q, block_k)
-    nq, nk = sq // block_q, sk // block_k
+    block_q = min(block_q, _round_up(sq, 32))
+    block_k = min(block_k, _round_up(sk, 32))
+    sq_pad, sk_pad = _round_up(sq, block_q), _round_up(sk, block_k)
+    if sq_pad != sq:
+        q_q = jnp.pad(q_q, ((0, 0), (0, 0), (0, sq_pad - sq), (0, 0)))
+    if sk_pad != sk:
+        pad = ((0, 0), (0, 0), (0, sk_pad - sk), (0, 0))
+        k_q, v_q = jnp.pad(k_q, pad), jnp.pad(v_q, pad)
+    nq, nk = sq_pad // block_q, sk_pad // block_k
 
-    qf = q_q.reshape(b * hq, sq, d)
-    kf = k_q.reshape(b * hkv, sk, d)
-    vf = v_q.reshape(b * hkv, sk, d)
+    qf = q_q.reshape(b * hq, sq_pad, d)
+    kf = k_q.reshape(b * hkv, sk_pad, d)
+    vf = v_q.reshape(b * hkv, sk_pad, d)
 
     # NB: with PrefetchScalarGridSpec the index maps receive the scalar refs
     # as trailing arguments.
@@ -242,8 +307,8 @@ def splitmax_attention_pallas(
             pl.BlockSpec((1, block_q, d), q_index),
             pl.BlockSpec((1, block_k, d), kv_index),
             pl.BlockSpec((1, block_k, d), kv_index),
-            pl.BlockSpec((256, 128), lambda *_: (0, 0)),
-            pl.BlockSpec((256, 128), lambda *_: (0, 0)),
+            pl.BlockSpec((TABLE_SUBLANES, 2 * LANES), lambda *_: (0, 0)),
+            pl.BlockSpec((TABLE_SUBLANES, 2 * LANES), lambda *_: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), out_index),
         scratch_shapes=[
@@ -255,11 +320,11 @@ def splitmax_attention_pallas(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((b * hq, sq_pad, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(scalars, qf, kf, vf, _replicate_table(exp_lut),
       _replicate_table(recip_lut))
 
-    return out.reshape(b, hq, sq, d)
+    return out.reshape(b, hq, sq_pad, d)[:, :, :sq]

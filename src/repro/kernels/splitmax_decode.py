@@ -26,7 +26,7 @@ The fused entry points take the *float* query and run the whole CIM datapath
 — quantize -> QK^T -> 32b->8b requant -> exp-LUT split accumulation -> PV ->
 reciprocal LUT — inside one kernel instance, with no HBM writes between
 stages.  The absmax scale ``s_q`` rides in scalar prefetch; the int8 grid
-snap happens once per (batch, kv-head) instance at ``ki == 0`` into an int32
+snap happens once per (batch, kv-head) instance at ``ki == 0`` into an int8
 VMEM scratch tile, bit-identical to ``repro.core.quantization.quantize``
 (same round + clip), so the fused path and the composed path (quantize op,
 then the int8 kernel) agree to the bit.  This mirrors CIMple's dual-banked
@@ -58,11 +58,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 from repro.core.lut import LUTConfig
-from repro.kernels.splitmax_attn import (_onehot_lookup, _recip_lut_inline,
-                                         _replicate_table)
+from repro.kernels.splitmax_attn import (LANES, TABLE_SUBLANES, _exact_pv,
+                                         _qk_scores, _recip_lut_inline,
+                                         _replicate_table, _table_lookup)
+
+
+def _table_spec() -> pl.BlockSpec:
+    """BlockSpec of an (8, 256) f32 LUT ref, resident for the whole grid."""
+    return pl.BlockSpec((TABLE_SUBLANES, 2 * LANES), lambda *_: (0, 0))
 
 
 def _accumulate_tile(q, k, v, *, m_z, cache_len, k_start, window, windowed,
@@ -70,17 +74,15 @@ def _accumulate_tile(q, k, v, *, m_z, cache_len, k_start, window, windowed,
                      block_k: int, lut_mode: str):
     """One k-tile of the split-softmax accumulation (shared dense/paged).
 
-    q (G_pad, D) int8-as-int32, k/v (block_k, D) int8 tiles; ``k_start`` is
-    the tile's absolute position in the slot's logical sequence (for paged
-    caches that is the *table* position, not the pool position).
+    q (G_pad, D), k/v (block_k, D) int8 tiles; ``k_start`` is the tile's
+    absolute position in the slot's logical sequence (for paged caches that
+    is the *table* position, not the pool position).
     """
-    z32 = jax.lax.dot_general(q, k.astype(jnp.int32),
-                              (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.int32)
+    z32 = _qk_scores(q, k)
     z_q = jnp.clip(jnp.round(z32.astype(jnp.float32) * m_z),
                    -128, 127).astype(jnp.int32)
     if lut_mode == "onehot":
-        e = _onehot_lookup(z_q + 128, exp_ref)
+        e = _table_lookup(z_q + 128, exp_ref)
     else:
         e = jnp.round(jnp.exp((z_q - 127).astype(jnp.float32)
                               * cfg.scale_z) * (1 << cfg.exp_frac_bits))
@@ -90,9 +92,7 @@ def _accumulate_tile(q, k, v, *, m_z, cache_len, k_start, window, windowed,
     if windowed:
         mask &= cols > cache_len - 1 - window
     e = jnp.where(mask, e, 0.0)
-    acc_ref[...] += jax.lax.dot_general(
-        e, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += _exact_pv(e, v)
     s_ref[:, :1] += jnp.sum(e, axis=1, keepdims=True)
 
 
@@ -111,11 +111,11 @@ def _quantize_q_tile(q_f32, s_q):
     """In-kernel stage 0 of the fused datapath: fp q tile -> int8 grid.
 
     Bit-identical to :func:`repro.core.quantization.quantize` (round to
-    nearest even, saturate), held as int32 because that is what the MXU
-    matmul consumes anyway.
+    nearest even, saturate), held as int8 because that is what the MXU
+    matmul consumes.
     """
     return jnp.clip(jnp.round(q_f32.astype(jnp.float32) / s_q),
-                    -128, 127).astype(jnp.int32)
+                    -128, 127).astype(jnp.int32).astype(jnp.int8)
 
 
 def _decode_kernel(
@@ -128,13 +128,13 @@ def _decode_kernel(
     q_ref,                  # (1, G_pad, D) int8 (composed) / f32 (fused)
     k_ref,                  # (1, block_k, D) int8
     v_ref,                  # (1, block_k, D) int8
-    exp_ref, recip_ref,     # (256, 128) f32
+    exp_ref, recip_ref,     # (8, 256) f32
     # output
     out_ref,                # (1, G_pad, D) f32
     # scratch
     acc_ref,                # (G_pad, D) f32
     s_ref,                  # (G_pad, 128) f32
-    *extra_scratch,         # fused only: (G_pad, D) int32 quantized q
+    *extra_scratch,         # fused only: (G_pad, D) int8 quantized q
     cfg: LUTConfig,
     hkv: int,
     block_k: int,
@@ -171,7 +171,7 @@ def _decode_kernel(
 
     @pl.when(live)
     def _compute():
-        q = qq_ref[...] if fused else q_ref[0].astype(jnp.int32)
+        q = qq_ref[...] if fused else q_ref[0]
         _accumulate_tile(
             q, k_ref[0], v_ref[0],
             m_z=m_z, cache_len=cache_len, k_start=k_start, window=window,
@@ -195,13 +195,13 @@ def _paged_decode_kernel(
     q_ref,                  # (1, G_pad, D) int8 (composed) / f32 (fused)
     k_ref,                  # (1, 1, block_k, D) int8 — pool tile via table
     v_ref,                  # (1, 1, block_k, D) int8
-    exp_ref, recip_ref,     # (256, 128) f32
+    exp_ref, recip_ref,     # (8, 256) f32
     # output
     out_ref,                # (1, G_pad, D) f32
     # scratch
     acc_ref,                # (G_pad, D) f32
     s_ref,                  # (G_pad, 128) f32
-    *extra_scratch,         # fused only: (G_pad, D) int32 quantized q
+    *extra_scratch,         # fused only: (G_pad, D) int8 quantized q
     cfg: LUTConfig,
     hkv: int,
     block_k: int,
@@ -243,7 +243,7 @@ def _paged_decode_kernel(
 
     @pl.when(live)
     def _compute():
-        q = qq_ref[...] if fused else q_ref[0].astype(jnp.int32)
+        q = qq_ref[...] if fused else q_ref[0]
         _accumulate_tile(
             q, k_ref[0, 0], v_ref[0, 0],
             m_z=m_z, cache_len=cache_len, k_start=k_start, window=window,
@@ -417,7 +417,7 @@ def _dense_decode_call(q, k_cache, v_cache, m_z, s_q, s_v, cache_len,
         pltpu.VMEM((g_pad, 128), jnp.float32),
     ]
     if fused:
-        scratch.append(pltpu.VMEM((g_pad, d), jnp.int32))
+        scratch.append(pltpu.VMEM((g_pad, d), jnp.int8))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -426,8 +426,8 @@ def _dense_decode_call(q, k_cache, v_cache, m_z, s_q, s_v, cache_len,
             pl.BlockSpec((1, g_pad, d), lambda bh, ki, *_: (bh, 0, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, ki, *_: (bh, ki, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, ki, *_: (bh, ki, 0)),
-            pl.BlockSpec((256, 128), lambda *_: (0, 0)),
-            pl.BlockSpec((256, 128), lambda *_: (0, 0)),
+            _table_spec(),
+            _table_spec(),
         ],
         out_specs=pl.BlockSpec((1, g_pad, d), lambda bh, ki, *_: (bh, 0, 0)),
         scratch_shapes=scratch,
@@ -437,7 +437,7 @@ def _dense_decode_call(q, k_cache, v_cache, m_z, s_q, s_v, cache_len,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, g_pad, d), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(cache_len.astype(jnp.int32), _sv_window_scalars(s_v, window),
@@ -475,7 +475,7 @@ def _paged_decode_call(q, k_pages, v_pages, block_table, m_z, s_q, s_v,
         pltpu.VMEM((g_pad, 128), jnp.float32),
     ]
     if fused:
-        scratch.append(pltpu.VMEM((g_pad, d), jnp.int32))
+        scratch.append(pltpu.VMEM((g_pad, d), jnp.int8))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
@@ -484,8 +484,8 @@ def _paged_decode_call(q, k_pages, v_pages, block_table, m_z, s_q, s_v,
             pl.BlockSpec((1, g_pad, d), lambda bh, ki, *_: (bh, 0, 0)),
             pl.BlockSpec((1, 1, block_k, d), kv_index),
             pl.BlockSpec((1, 1, block_k, d), kv_index),
-            pl.BlockSpec((256, 128), lambda *_: (0, 0)),
-            pl.BlockSpec((256, 128), lambda *_: (0, 0)),
+            _table_spec(),
+            _table_spec(),
         ],
         out_specs=pl.BlockSpec((1, g_pad, d), lambda bh, ki, *_: (bh, 0, 0)),
         scratch_shapes=scratch,
@@ -495,7 +495,7 @@ def _paged_decode_call(q, k_pages, v_pages, block_table, m_z, s_q, s_v,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, g_pad, d), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(cache_len.astype(jnp.int32), block_table.astype(jnp.int32),
@@ -551,14 +551,14 @@ def _dense_verify_call(q, k_cache, v_cache, m_z, s_q, s_v, cache_len,
             pl.BlockSpec((1, rows, d), lambda bh, ki, *_: (bh, 0, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, ki, *_: (bh, ki, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, ki, *_: (bh, ki, 0)),
-            pl.BlockSpec((256, 128), lambda *_: (0, 0)),
-            pl.BlockSpec((256, 128), lambda *_: (0, 0)),
+            _table_spec(),
+            _table_spec(),
         ],
         out_specs=pl.BlockSpec((1, rows, d), lambda bh, ki, *_: (bh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rows, d), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.int32),
+            pltpu.VMEM((rows, d), jnp.int8),
         ],
     )
 
@@ -566,7 +566,7 @@ def _dense_verify_call(q, k_cache, v_cache, m_z, s_q, s_v, cache_len,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, rows, d), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(cache_len.astype(jnp.int32), _sv_window_scalars(s_v, window),
@@ -605,14 +605,14 @@ def _paged_verify_call(q, k_pages, v_pages, block_table, m_z, s_q, s_v,
             pl.BlockSpec((1, rows, d), lambda bh, ki, *_: (bh, 0, 0)),
             pl.BlockSpec((1, 1, block_k, d), kv_index),
             pl.BlockSpec((1, 1, block_k, d), kv_index),
-            pl.BlockSpec((256, 128), lambda *_: (0, 0)),
-            pl.BlockSpec((256, 128), lambda *_: (0, 0)),
+            _table_spec(),
+            _table_spec(),
         ],
         out_specs=pl.BlockSpec((1, rows, d), lambda bh, ki, *_: (bh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rows, d), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.int32),
+            pltpu.VMEM((rows, d), jnp.int8),
         ],
     )
 
@@ -620,7 +620,7 @@ def _paged_verify_call(q, k_pages, v_pages, block_table, m_z, s_q, s_v,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, rows, d), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(cache_len.astype(jnp.int32), block_table.astype(jnp.int32),

@@ -71,7 +71,8 @@ class PagedKVEngine(base.CacheEngine):
             st.make_paged_prefill_step(cfg, calibrate=False),
             donate_argnums=(2,))
         self.decode_step = jax.jit(st.make_decode_step(cfg),
-                                   donate_argnums=(2,))
+                                   donate_argnums=(2,),
+                                   compiler_options=st.EXACT_ROUNDING)
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def release_step(cache, slot):

@@ -495,7 +495,8 @@ def run_speculative(params, cfg, prompts: List[np.ndarray], *, slots: int,
                          donate_argnums=(2,))
     draft_loop = jax.jit(st.make_draft_loop(dcfg, gamma),
                          donate_argnums=(2,))
-    verify_step = jax.jit(st.make_verify_step(cfg), donate_argnums=(2,))
+    verify_step = jax.jit(st.make_verify_step(cfg), donate_argnums=(2,),
+                          compiler_options=st.EXACT_ROUNDING)
 
     @jax.jit
     def select_targets(vlogits):

@@ -91,6 +91,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch import compile_cache
 from repro.launch import faults as faults_mod
 from repro.launch import scheduler as sched
 from repro.launch import steps as st
@@ -504,6 +505,7 @@ def main(argv=None) -> None:
                          "occupancy) to this path")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     arch = get_arch(args.arch)
     cfg = arch.smoke if args.smoke else arch.config

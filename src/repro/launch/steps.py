@@ -180,6 +180,15 @@ def make_paged_prefill_step(cfg: ModelConfig, *, calibrate: bool):
     return prefill_step
 
 
+# ``jax.jit(..., compiler_options=EXACT_ROUNDING)`` for decode and verify,
+# which must give a token the same K/V and logits bit for bit.  Their
+# programs fuse differently, and by default XLA lets a fusion keep a bf16
+# value in f32 ("excess precision"): on TPU the two programs then round the
+# same token's K/V and logits differently.  Without it every bf16 value is
+# rounded where the program says, whatever the fusion.
+EXACT_ROUNDING = {"xla_allow_excess_precision": False}
+
+
 def make_decode_step(cfg: ModelConfig):
     """(params, token (B,), cache) -> (logits (B, V), cache)."""
 
@@ -203,7 +212,8 @@ def make_verify_step(cfg: ModelConfig):
 
     The speculative target step: one fused multi-token launch whose
     ``logits[:, t]`` is bitwise what ``make_decode_step`` would have
-    produced after accepting ``tokens[:, :t+1]`` (paged caches only).
+    produced after accepting ``tokens[:, :t+1]`` (paged caches only), when
+    both are compiled with :data:`EXACT_ROUNDING`.
     """
     assert cfg.family != "encdec", "speculative serving is decoder-only"
 

@@ -31,6 +31,7 @@ from repro.data.pipeline import DataConfig, batch_for_step
 from repro.dist import compression
 from repro.dist import sharding as sh
 from repro.dist.straggler import StragglerWatchdog
+from repro.launch import compile_cache
 from repro.launch import steps as st
 from repro.launch.mesh import logical_rules, make_production_mesh
 from repro.optim import adamw
@@ -56,6 +57,7 @@ def main(argv=None) -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     arch = get_arch(args.arch)
     cfg = arch.smoke if args.smoke else arch.config

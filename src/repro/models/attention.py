@@ -209,26 +209,30 @@ def attn_block_verify_paged(params, x, layer_cache: Dict, cfg: ModelConfig, *,
                             ) -> Tuple[jax.Array, Dict]:
     """T-token speculative verify against one layer's paged pool slice.
 
-    ``x (B, T, d_in)`` carries the T verify tokens (last accepted token +
-    the drafts); their K/V are quantized with the static scales, scattered
-    through the block table at positions ``length + t``, and all T queries
-    stream against the pool in one fused verify launch with per-token
-    causal lengths.  The T-token twin of :func:`attn_block_decode_paged` —
-    rejected tokens are rolled back later by the scheduler via
-    ``paged_kv.truncate_lengths``, never here.
+    ``x (B*T, 1, d_in)`` carries the T verify tokens of each slot (last
+    accepted token + the drafts) as slot-major rows, in decode's activation
+    shape (see ``transformer.verify_step``); their K/V are quantized with
+    the static scales, scattered through the block table at positions
+    ``length + t``, and all T queries stream against the pool in one fused
+    verify launch with per-token causal lengths.  The T-token twin of
+    :func:`attn_block_decode_paged` — rejected tokens are rolled back later
+    by the scheduler via ``paged_kv.truncate_lengths``, never here.
     """
-    b, t, _ = x.shape
     dt = cfg.compute_dtype
     hd = cfg.hd
     spec = spec or cfg.attn_spec(serve=True)
     table = layer_cache["block_table"]
     base_len = layer_cache["length"]
-    positions = base_len[:, None] + jnp.arange(t)[None, :]   # (B, T)
-    q, k, v = _project_qkv(params, x, cfg, positions)
+    b = base_len.shape[0]
+    t = x.shape[0] // b
+    positions = (base_len[:, None] + jnp.arange(t)[None, :]).reshape(b * t, 1)
+    q, k, v = jax.lax.optimization_barrier(
+        _project_qkv(params, x, cfg, positions))            # (B*T, H, 1, hd)
+    q = q.reshape(b, t, cfg.n_heads, hd).transpose(0, 2, 1, 3)   # (B,H,T,hd)
     s_k = layer_cache["scale_k"].reshape(())
     s_v = layer_cache["scale_v"].reshape(())
-    k_new = qlib.quantize(k, s_k).transpose(0, 2, 1, 3)      # (B, T, Hkv, hd)
-    v_new = qlib.quantize(v, s_v).transpose(0, 2, 1, 3)
+    k_new = qlib.quantize(k, s_k).reshape(b, t, cfg.n_kv_heads, hd)
+    v_new = qlib.quantize(v, s_v).reshape(b, t, cfg.n_kv_heads, hd)
     k_pages = paged_kv.append_kv(layer_cache["k_pages"], table, base_len,
                                  k_new)
     v_pages = paged_kv.append_kv(layer_cache["v_pages"], table, base_len,
@@ -236,7 +240,7 @@ def attn_block_verify_paged(params, x, layer_cache: Dict, cfg: ModelConfig, *,
     new_len = base_len + t                         # includes all T tokens
     out = core_attn.paged_verify_attention(
         q, k_pages, v_pages, table, s_k, s_v, new_len, spec)
-    out = out.transpose(0, 2, 1, 3).reshape(b, t, cfg.n_heads * hd)
+    out = out.transpose(0, 2, 1, 3).reshape(b * t, 1, cfg.n_heads * hd)
     out = L.linear_apply(params["wo"], out, dtype=dt)
     new_cache = dict(layer_cache, k_pages=k_pages, v_pages=v_pages,
                      length=new_len)

@@ -646,12 +646,21 @@ def verify_step(params, tokens, cfg: ModelConfig, cache: Dict
     ``tokens[:, :t+1]``.  The cache comes back T tokens longer; the
     scheduler truncates it to the accepted prefix via
     ``paged_kv.truncate_lengths``.  Paged dense/moe families only.
+
+    The T tokens travel as B*T rows of one token each, decode's own
+    ``(rows, 1, d)`` activation shape, and meet only inside the attention
+    kernel.  With a ``(B, T, d)`` activation XLA compiles the projections,
+    RoPE, MLP and LM head into other fusions than decode's, which on TPU
+    round some rows differently and break the bitwise contract even with
+    ``steps.EXACT_ROUNDING``.  For the same reason the optimization
+    barriers keep XLA from folding the ``(B*T, ...) -> (B, T, ...)``
+    reshapes back into those fusions.
     """
     if cfg.family not in ("dense", "moe"):
         raise NotImplementedError(
             f"speculative verify supports paged dense/moe, not {cfg.family}")
-    t = tokens.shape[1]
-    x = embed_tokens(params, tokens, cfg)               # (B, T, d)
+    b, t = tokens.shape
+    x = embed_tokens(params, tokens.reshape(b * t, 1), cfg)   # (B*T, 1, d)
     segs = _layer_kinds(cfg)
     offset = 0
     for seg_params, (kind, n) in zip(params["segments"], segs):
@@ -660,7 +669,8 @@ def verify_step(params, tokens, cfg: ModelConfig, cache: Dict
         offset += n
     cache = dict(cache, length=cache["length"] + t)
     cache["kv"] = dict(cache["kv"], length=cache["kv"]["length"] + t)
-    return unembed(params, x, cfg), cache
+    logits = jax.lax.optimization_barrier(unembed(params, x, cfg))
+    return logits.reshape(b, t, -1), cache
 
 
 def _verify_segment(seg_params, x, cfg, kind, n, offset, cache):

@@ -155,3 +155,63 @@ def test_denominator_bitexact_small_n(rng):
     # recip-LUT indices must agree exactly -> identical normalization
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# ragged prefill, the exact LUT read and the exact e.V split
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("sq,sk,causal", [
+    (200, 200, True), (33, 33, True), (200, 33, False), (33, 200, False)])
+def test_splitmax_attention_ragged_bitwise(rng, sq, sk, causal):
+    """Any Sq/Sk: the kernel pads to its tiles and masks the padded keys.
+    With |v| <= 2 every f32 partial sum is an integer below 2^24, so the
+    kernel and the oracle agree bit for bit whatever their order."""
+    q, k, _ = _qkv(rng, 1, 8, 2, sq, sk, 64)
+    v = rng.integers(-2, 3, k.shape).astype(np.int8)
+    args = (q, k, v, *SCALES, EXP_LUT, RECIP_LUT)
+    ref = ops.splitmax_attention(*args, cfg=CFG, impl="ref", causal=causal)
+    ker = ops.splitmax_attention(*args, cfg=CFG, impl="interpret",
+                                 causal=causal)
+    assert ker.shape == (1, 8, sq, 64)
+    assert np.array_equal(np.asarray(ker), np.asarray(ref))
+
+
+def test_splitmax_xla_ragged_long_kv(rng):
+    """The XLA twin pads a key length that its 512-wide chunks do not
+    divide, instead of asserting."""
+    q, k, v = _qkv(rng, 1, 4, 2, 64, 600, 64)
+    args = (q, k, v, *SCALES, EXP_LUT, RECIP_LUT)
+    ref = ops.splitmax_attention(*args, cfg=CFG, impl="ref", causal=False)
+    xla = ops.splitmax_attention(*args, cfg=CFG, impl="xla", causal=False)
+    np.testing.assert_allclose(np.asarray(xla), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("cols", [1, 32, 128, 200, 256])
+def test_table_lookup_matches_take(rng, cols):
+    """The in-kernel lane gather reads exactly ``jnp.take`` of the table."""
+    from jax.experimental import pallas as pl
+    from repro.kernels.splitmax_attn import _replicate_table, _table_lookup
+
+    idx = jnp.asarray(rng.integers(0, 256, (16, cols)), jnp.int32)
+    out = pl.pallas_call(
+        lambda i_ref, t_ref, o_ref: o_ref.__setitem__(
+            Ellipsis, _table_lookup(i_ref[...], t_ref)),
+        out_shape=jax.ShapeDtypeStruct(idx.shape, jnp.float32),
+        interpret=True)(idx, _replicate_table(jnp.asarray(EXP_LUT)))
+    want = jnp.take(jnp.asarray(EXP_LUT), idx).astype(jnp.float32)
+    assert np.array_equal(np.asarray(out), np.asarray(want))
+
+
+@pytest.mark.parametrize("cols", [32, 128, 512])
+def test_exact_pv_is_correctly_rounded(rng, cols):
+    """e.V through the bf16 hi/lo split equals the exact integer product
+    rounded once to f32, for LUT values up to 2^15 and int8 V."""
+    from repro.kernels.splitmax_attn import _exact_pv
+
+    e = rng.integers(0, 2 ** 15 + 1, (8, cols))
+    v = rng.integers(-128, 128, (cols, 64))
+    got = _exact_pv(jnp.asarray(e, jnp.float32), jnp.asarray(v, jnp.int8))
+    want = (e.astype(np.int64) @ v.astype(np.int64)).astype(np.float32)
+    assert np.array_equal(np.asarray(got), want)

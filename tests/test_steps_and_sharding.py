@@ -227,10 +227,11 @@ print("TINY-MESH-OK")
 def test_tiny_mesh_dryrun_subprocess():
     """8 fake devices in a subprocess (keeps this process at 1 device):
     the full lower+compile+analyze path on a (2,4) mesh."""
-    env = dict(os.environ,
+    # the child stays on the CPU: with a TPU library installed it must not
+    # try to load it while another test worker holds it
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
                                        "src"))
-    env.pop("JAX_PLATFORMS", None)
     out = subprocess.run([sys.executable, "-c", DRYRUN_SNIPPET], env=env,
                          capture_output=True, text=True, timeout=900)
     assert "TINY-MESH-OK" in out.stdout, out.stderr[-2000:]

@@ -1,0 +1,134 @@
+"""Compile the serving path's Pallas kernels for a described TPU v5e.
+
+Nothing runs here: each test lowers one kernel at TinyLlama-1.1B widths
+(Hq=32, Hkv=4, head_dim=64, a paged pool of 32-token blocks) for a v5e chip
+that is described, not attached, and checks that Mosaic emitted the kernel
+(``tpu_custom_call``).  Interpret mode cannot see what the chip's compiler
+refuses — unsupported shape casts, int32 operands on the MXU, tiles that do
+not fit VMEM — so these compiles guard the kernels between chip runs.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and a module that loaded it while being
+collected would give parallel test workers different test lists.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import split_softmax as ss
+from repro.core.lut import LUTConfig
+from repro.kernels import ops
+
+HQ, HKV, D = 32, 4, 64          # configs/tinyllama_1p1b.py
+B, BLOCK_K, MAX_BLOCKS = 8, 32, 64
+GAMMA = 4
+CFG = LUTConfig(scale_z=8.0 / 127)
+EXP_LUT, RECIP_LUT = ss.make_luts(CFG)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this environment
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent compile cache off: a compile
+    for a described device is written to the cache but cannot be read back
+    without the chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+SCALAR = ((), jnp.float32)
+
+
+@pytest.mark.parametrize("seq", [512, 200])
+def test_prefill_compiles(one_chip, seq):
+    def prefill(q, k, v, s_q, s_k, s_v):
+        return ops.splitmax_attention(q, k, v, s_q, s_k, s_v, EXP_LUT,
+                                      RECIP_LUT, cfg=CFG, causal=True,
+                                      impl="pallas")
+    _compile(prefill, one_chip,
+             ((1, HQ, seq, D), jnp.int8), ((1, HKV, seq, D), jnp.int8),
+             ((1, HKV, seq, D), jnp.int8), SCALAR, SCALAR, SCALAR)
+
+
+POOL = ((1 + B * MAX_BLOCKS, HKV, BLOCK_K, D), jnp.int8)
+TABLE = ((B, MAX_BLOCKS), jnp.int32)
+LENS = ((B,), jnp.int32)
+DENSE_CACHE = ((B, HKV, MAX_BLOCKS * BLOCK_K, D), jnp.int8)
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_paged_decode_compiles(one_chip, fused):
+    op = ops.splitmax_decode_fused_paged if fused else ops.splitmax_decode_paged
+    q_dtype = jnp.bfloat16 if fused else jnp.int8
+
+    def decode(q, kp, vp, table, s_q, s_k, s_v, lens):
+        return op(q, kp, vp, table, s_q, s_k, s_v, lens, EXP_LUT, RECIP_LUT,
+                  cfg=CFG, impl="pallas")
+    _compile(decode, one_chip, ((B, HQ, D), q_dtype), POOL, POOL, TABLE,
+             ((B,), jnp.float32), SCALAR, SCALAR, LENS)
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_dense_decode_compiles(one_chip, fused):
+    op = ops.splitmax_decode_fused if fused else ops.splitmax_decode
+    q_dtype = jnp.bfloat16 if fused else jnp.int8
+
+    def decode(q, k, v, s_q, s_k, s_v, lens):
+        return op(q, k, v, s_q, s_k, s_v, lens, EXP_LUT, RECIP_LUT, cfg=CFG,
+                  impl="pallas")
+    _compile(decode, one_chip, ((B, HQ, D), q_dtype), DENSE_CACHE,
+             DENSE_CACHE, ((B,), jnp.float32), SCALAR, SCALAR, LENS)
+
+
+@pytest.mark.parametrize("paged", [True, False])
+def test_verify_compiles(one_chip, paged):
+    q = ((B, HQ, GAMMA, D), jnp.bfloat16)
+    s_q = ((B, GAMMA), jnp.float32)
+    if paged:
+        def verify(q, kp, vp, table, s_q, s_k, s_v, lens):
+            return ops.splitmax_decode_fused_verify_paged(
+                q, kp, vp, table, s_q, s_k, s_v, lens, EXP_LUT, RECIP_LUT,
+                cfg=CFG, impl="pallas")
+        _compile(verify, one_chip, q, POOL, POOL, TABLE, s_q, SCALAR, SCALAR,
+                 LENS)
+    else:
+        def verify(q, k, v, s_q, s_k, s_v, lens):
+            return ops.splitmax_decode_fused_verify(
+                q, k, v, s_q, s_k, s_v, lens, EXP_LUT, RECIP_LUT, cfg=CFG,
+                impl="pallas")
+        _compile(verify, one_chip, q, DENSE_CACHE, DENSE_CACHE, s_q, SCALAR,
+                 SCALAR, LENS)
+
+
+@pytest.mark.parametrize("requant", [False, True])
+def test_int8_matmul_compiles(one_chip, requant):
+    def gemm(x, w, mult):
+        return ops.int8_matmul(x, w, mult if requant else None,
+                               impl="pallas")
+    _compile(gemm, one_chip, ((512, 2048), jnp.int8),
+             ((2048, 2048), jnp.int8), SCALAR)
