@@ -84,6 +84,7 @@ def int8_matmul_pallas(
     return pl.pallas_call(
         functools.partial(_int8_matmul_kernel,
                           num_k_blocks=k // block_k, requant=requant),
+        name="int8_matmul_pallas",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         compiler_params=pltpu.CompilerParams(

@@ -319,6 +319,7 @@ def splitmax_attention_pallas(
 
     out = pl.pallas_call(
         kernel,
+        name="splitmax_attention_pallas",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hq, sq_pad, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(

@@ -435,6 +435,8 @@ def _dense_decode_call(q, k_cache, v_cache, m_z, s_q, s_v, cache_len,
 
     out = pl.pallas_call(
         kernel,
+        name=("splitmax_decode_fused_pallas" if fused
+              else "splitmax_decode_pallas"),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, g_pad, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -493,6 +495,8 @@ def _paged_decode_call(q, k_pages, v_pages, block_table, m_z, s_q, s_v,
 
     out = pl.pallas_call(
         kernel,
+        name=("splitmax_decode_fused_paged_pallas" if fused
+              else "splitmax_decode_paged_pallas"),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, g_pad, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -564,6 +568,7 @@ def _dense_verify_call(q, k_cache, v_cache, m_z, s_q, s_v, cache_len,
 
     out = pl.pallas_call(
         kernel,
+        name="splitmax_decode_fused_verify_pallas",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, rows, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -618,6 +623,7 @@ def _paged_verify_call(q, k_pages, v_pages, block_table, m_z, s_q, s_v,
 
     out = pl.pallas_call(
         kernel,
+        name="splitmax_decode_fused_verify_paged_pallas",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, rows, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(

@@ -173,6 +173,14 @@ def run_schedule(engine: engines_base.CacheEngine,
     through the engine.  When ``deadline_ms`` is set, admission picks the
     queued request with the least remaining budget first (earliest-
     deadline-first) instead of FIFO; victims still resume first.
+
+    While the JAX profiler runs, the loop writes host spans into its trace,
+    on the device planes' clock: ``sched.step`` per iteration (``step_num``;
+    ``active``, the slots it decodes), ``sched.admit`` per admission
+    (``rid``, ``slot``, ``prompt_len``) and ``sched.wait`` wherever the host
+    blocks on a device value (``tokens``, the tokens the read brings to the
+    host: the first token inside ``sched.admit``, one per decoded slot in a
+    step).  A request's tokens reach the host as its waits end.
     """
     requests = len(prompts)
     slots = engine.slots
@@ -261,167 +269,181 @@ def run_schedule(engine: engines_base.CacheEngine,
 
         t0 = time.time()
         while active or queue:
-            ts_iter = time.perf_counter()
-            prefills0 = stats["slot_prefills"]
-            preempts0 = health.counters["preemptions"]
-            inj.on_step(step)
-            if paged:
-                inj.squeeze_pool(step, alloc)
-            fslot = inj.force_preempt(step)
-            if fslot is not None and fslot in active:
-                preempt(fslot, reason="fault")
+            with jax.profiler.StepTraceAnnotation("sched.step",
+                                                  step_num=step) as span:
+                ts_iter = time.perf_counter()
+                prefills0 = stats["slot_prefills"]
+                preempts0 = health.counters["preemptions"]
+                inj.on_step(step)
+                if paged:
+                    inj.squeeze_pool(step, alloc)
+                fslot = inj.force_preempt(step)
+                if fslot is not None and fslot in active:
+                    preempt(fslot, reason="fault")
 
-            # ---- growth: cover this step's write position for every slot;
-            # on exhaustion, preempt a victim and retry --------------------
-            if paged:
-                for slot in list(sorted(active)):
-                    if slot not in active:
-                        continue            # preempted by an earlier grower
-                    rid = active[slot]
-                    upto = len(prompts[rid]) + len(generated[rid])
-                    while engine.short(slot, upto) > 0:
-                        try:
-                            start, ids = engine.grow_blocks(
-                                slot, engine.short(slot, upto))
-                        except paged_kv.BlockAllocationError as e:
-                            health.event("pool_pressure", step, slot=slot,
-                                         requested=e.requested, free=e.free,
-                                         live=e.live,
-                                         high_water=e.high_water)
-                            victim = pick_victim(
-                                active, slot, preempt_policy, admit_seq,
-                                lambda s: gens[active[s]]
-                                - len(generated[active[s]]))
-                            if victim is None:
-                                # sole active slot: park it in the queue and
-                                # wait for the pool (fault hold) to drain
-                                preempt(slot, reason="self")
-                                break
-                            preempt(victim, reason="growth")
-                            continue
-                        for j, b in enumerate(ids):
-                            cache = engine.grow_write(cache, slot,
-                                                      start + j, b)
+                # ---- growth: cover this step's write position for every
+                # slot; on exhaustion, preempt a victim and retry ----------
+                if paged:
+                    for slot in list(sorted(active)):
+                        if slot not in active:
+                            continue        # preempted by an earlier grower
+                        rid = active[slot]
+                        upto = len(prompts[rid]) + len(generated[rid])
+                        while engine.short(slot, upto) > 0:
+                            try:
+                                start, ids = engine.grow_blocks(
+                                    slot, engine.short(slot, upto))
+                            except paged_kv.BlockAllocationError as e:
+                                health.event("pool_pressure", step,
+                                             slot=slot, requested=e.requested,
+                                             free=e.free, live=e.live,
+                                             high_water=e.high_water)
+                                victim = pick_victim(
+                                    active, slot, preempt_policy, admit_seq,
+                                    lambda s: gens[active[s]]
+                                    - len(generated[active[s]]))
+                                if victim is None:
+                                    # sole active slot: park it in the
+                                    # queue and wait for the pool (fault
+                                    # hold) to drain
+                                    preempt(slot, reason="self")
+                                    break
+                                preempt(victim, reason="growth")
+                                continue
+                            for j, b in enumerate(ids):
+                                cache = engine.grow_write(cache, slot,
+                                                          start + j, b)
 
-            # ---- admission: fill idle slots from the queue ---------------
-            idle = [s for s in range(slots) if s not in active]
-            while queue and idle:
-                if deadline_ms is None or len(queue) == 1:
-                    qi = 0
-                else:
-                    # earliest-deadline-first admission under --deadline-ms
-                    now = time.perf_counter()
-                    qi = min(range(len(queue)),
-                             key=lambda i: (budget_ms(queue[i], now), i))
-                rid = queue[qi]
-                # cover the prompt plus this step's decode write
-                need = engine.admission_need(rid)
-                if paged and alloc.free_count < need:
-                    health.count("admission_stalls")
-                    health.event("admission_stall", step, rid=rid,
-                                 need=need, free=alloc.free_count)
+                # ---- admission: fill idle slots from the queue -----------
+                idle = [s for s in range(slots) if s not in active]
+                while queue and idle:
+                    if deadline_ms is None or len(queue) == 1:
+                        qi = 0
+                    else:
+                        # earliest-deadline-first admission under --deadline-ms
+                        now = time.perf_counter()
+                        qi = min(range(len(queue)),
+                                 key=lambda i: (budget_ms(queue[i], now), i))
+                    rid = queue[qi]
+                    # cover the prompt plus this step's decode write
+                    need = engine.admission_need(rid)
+                    if paged and alloc.free_count < need:
+                        health.count("admission_stalls")
+                        health.event("admission_stall", step, rid=rid,
+                                     need=need, free=alloc.free_count)
+                        break
+                    del queue[qi]
+                    slot = idle.pop(0)
+                    with jax.profiler.TraceAnnotation(
+                            "sched.admit", rid=rid, slot=slot,
+                            prompt_len=len(prompts[rid])):
+                        last1, cache = engine.admit(cache, slot, rid)
+                        stats["slot_prefills"] += 1
+                        health.count("admissions")
+                        active[slot] = rid
+                        admit_seq[slot] = seq_counter[0]
+                        seq_counter[0] += 1
+                        if rid in resume_prefix:
+                            pre = resume_prefix.pop(rid)
+                            generated[rid] = [pre[0]]
+                            replay[rid] = pre[1:]
+                            first = pre[0]
+                            health.count("resumes")
+                            health.count("resumed_tokens_replayed",
+                                         len(pre) - 1)
+                            health.event("resume", step, rid=rid, slot=slot,
+                                         prefix_tokens=len(pre))
+                        else:
+                            admit_step0[rid] = step
+                            admit_t0[rid] = time.perf_counter()
+                            t1, ok1 = select(last1, [(rid, 0)])
+                            with jax.profiler.TraceAnnotation("sched.wait",
+                                                              tokens=1):
+                                t1, ok1 = jax.device_get((t1, ok1))
+                            if not ok1[0]:
+                                failed[rid] = []
+                                del active[slot]
+                                free_slot(slot)
+                                idle.insert(0, slot)
+                                health.count("nan_retired")
+                                health.event("nan_retired", step, rid=rid,
+                                             slot=slot, where="prefill")
+                                continue
+                            first = int(t1[0])
+                            generated[rid] = [first]
+                        tokens = _splice_token(tokens, jnp.int32(slot),
+                                               jnp.int32(first))
+
+                if not active:
+                    step += 1
+                    if queue:
+                        continue                # stalled; pool will drain
                     break
-                del queue[qi]
-                slot = idle.pop(0)
-                last1, cache = engine.admit(cache, slot, rid)
-                stats["slot_prefills"] += 1
-                health.count("admissions")
-                active[slot] = rid
-                admit_seq[slot] = seq_counter[0]
-                seq_counter[0] += 1
-                if rid in resume_prefix:
-                    pre = resume_prefix.pop(rid)
-                    generated[rid] = [pre[0]]
-                    replay[rid] = pre[1:]
-                    first = pre[0]
-                    health.count("resumes")
-                    health.count("resumed_tokens_replayed", len(pre) - 1)
-                    health.event("resume", step, rid=rid, slot=slot,
-                                 prefix_tokens=len(pre))
-                else:
-                    admit_step0[rid] = step
-                    admit_t0[rid] = time.perf_counter()
-                    t1, ok1 = select(last1, [(rid, 0)])
-                    if not bool(np.asarray(ok1)[0]):
-                        failed[rid] = []
+
+                # ---- decode one token per slot ---------------------------
+                span.set_metadata(active=len(active))
+                ts = time.perf_counter()
+                logits, cache = engine.decode(tokens, cache)
+                logits = inj.corrupt_logits(step, logits)
+                rows: List = [None] * slots
+                for slot, rid in active.items():
+                    rows[slot] = (rid, len(generated[rid]))
+                toks, okv = select(logits, rows)
+                with jax.profiler.TraceAnnotation("sched.wait",
+                                                  tokens=len(active)):
+                    tok_host, ok_host = jax.device_get((toks, okv))
+                stats["step_s"].append(time.perf_counter() - ts)
+                stats["decode_steps"] += 1
+                tokens = toks
+
+                for slot in sorted(active):
+                    rid = active[slot]
+                    if not ok_host[slot]:
+                        # NaN/Inf logits: retire the request, keep the batch up
+                        failed[rid] = generated.pop(rid)
                         del active[slot]
+                        replay.pop(rid, None)
                         free_slot(slot)
-                        idle.insert(0, slot)
                         health.count("nan_retired")
                         health.event("nan_retired", step, rid=rid, slot=slot,
-                                     where="prefill")
+                                     where="decode")
                         continue
-                    first = int(np.asarray(t1)[0])
-                    generated[rid] = [first]
-                tokens = _splice_token(tokens, jnp.int32(slot),
-                                       jnp.int32(first))
-
-            if not active:
+                    if replay.get(rid):
+                        nxt = replay[rid].pop(0)
+                        if not replay[rid]:
+                            del replay[rid]
+                        if nxt != int(tok_host[slot]):
+                            # replay re-derives the recorded token (greedy by
+                            # determinism, sampled by count-addressed keys);
+                            # the splice is the safety net
+                            tokens = _splice_token(tokens, jnp.int32(slot),
+                                                   jnp.int32(nxt))
+                    else:
+                        nxt = int(tok_host[slot])
+                    generated[rid].append(nxt)
+                    if len(generated[rid]) >= gens[rid]:
+                        finished[rid] = generated.pop(rid)
+                        del active[slot]
+                        replay.pop(rid, None)
+                        free_slot(slot)
+                    elif ((deadline_steps is not None
+                           and step - admit_step0[rid] + 1 >= deadline_steps)
+                          or (deadline_ms is not None
+                              and (time.perf_counter() - admit_t0[rid]) * 1e3
+                              >= deadline_ms)):
+                        expired[rid] = generated.pop(rid)
+                        del active[slot]
+                        replay.pop(rid, None)
+                        free_slot(slot)
+                        health.count("deadline_cancelled")
+                        health.event("deadline", step, rid=rid, slot=slot,
+                                     tokens=len(expired[rid]))
+                watchdog.observe(
+                    step, time.perf_counter() - ts_iter,
+                    expect_slow=(stats["slot_prefills"] != prefills0
+                                 or health.counters["preemptions"]
+                                 != preempts0))
                 step += 1
-                if queue:
-                    continue                # stalled; pool will drain
-                break
-
-            # ---- decode one token per slot -------------------------------
-            ts = time.perf_counter()
-            logits, cache = engine.decode(tokens, cache)
-            logits = inj.corrupt_logits(step, logits)
-            rows: List = [None] * slots
-            for slot, rid in active.items():
-                rows[slot] = (rid, len(generated[rid]))
-            toks, okv = select(logits, rows)
-            tok_host, ok_host = jax.device_get((toks, okv))
-            stats["step_s"].append(time.perf_counter() - ts)
-            stats["decode_steps"] += 1
-            tokens = toks
-
-            for slot in sorted(active):
-                rid = active[slot]
-                if not ok_host[slot]:
-                    # NaN/Inf logits: retire the request, keep the batch up
-                    failed[rid] = generated.pop(rid)
-                    del active[slot]
-                    replay.pop(rid, None)
-                    free_slot(slot)
-                    health.count("nan_retired")
-                    health.event("nan_retired", step, rid=rid, slot=slot,
-                                 where="decode")
-                    continue
-                if replay.get(rid):
-                    nxt = replay[rid].pop(0)
-                    if not replay[rid]:
-                        del replay[rid]
-                    if nxt != int(tok_host[slot]):
-                        # replay re-derives the recorded token (greedy by
-                        # determinism, sampled by count-addressed keys);
-                        # the splice is the safety net
-                        tokens = _splice_token(tokens, jnp.int32(slot),
-                                               jnp.int32(nxt))
-                else:
-                    nxt = int(tok_host[slot])
-                generated[rid].append(nxt)
-                if len(generated[rid]) >= gens[rid]:
-                    finished[rid] = generated.pop(rid)
-                    del active[slot]
-                    replay.pop(rid, None)
-                    free_slot(slot)
-                elif ((deadline_steps is not None
-                       and step - admit_step0[rid] + 1 >= deadline_steps)
-                      or (deadline_ms is not None
-                          and (time.perf_counter() - admit_t0[rid]) * 1e3
-                          >= deadline_ms)):
-                    expired[rid] = generated.pop(rid)
-                    del active[slot]
-                    replay.pop(rid, None)
-                    free_slot(slot)
-                    health.count("deadline_cancelled")
-                    health.event("deadline", step, rid=rid, slot=slot,
-                                 tokens=len(expired[rid]))
-            watchdog.observe(
-                step, time.perf_counter() - ts_iter,
-                expect_slow=(stats["slot_prefills"] != prefills0
-                             or health.counters["preemptions"] != preempts0))
-            step += 1
 
         engine.finalize(health, inj)
         stats["leaked_blocks"] = engine.leaked()

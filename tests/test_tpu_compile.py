@@ -12,6 +12,7 @@ process may load the TPU library, and a module that loaded it while being
 collected would give parallel test workers different test lists.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,10 +55,17 @@ def one_chip(topo):
     cc.reset_cache()
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, kernel, sharding, *shapes):
+    """Compile ``fn`` and check that Mosaic emitted the Pallas kernel under
+    the ``name=`` its ``pallas_call`` gives: the profiler names the kernel's
+    device op after it, and the benchmark's trace reduction finds it so."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    assert all(re.search(rf"%{kernel}(\.\d+)? = ", ln)
+               and f"/{kernel}/pallas_call" in ln for ln in calls), calls
     return text
 
 
@@ -70,7 +78,7 @@ def test_prefill_compiles(one_chip, seq):
         return ops.splitmax_attention(q, k, v, s_q, s_k, s_v, EXP_LUT,
                                       RECIP_LUT, cfg=CFG, causal=True,
                                       impl="pallas")
-    _compile(prefill, one_chip,
+    _compile(prefill, "splitmax_attention_pallas", one_chip,
              ((1, HQ, seq, D), jnp.int8), ((1, HKV, seq, D), jnp.int8),
              ((1, HKV, seq, D), jnp.int8), SCALAR, SCALAR, SCALAR)
 
@@ -89,8 +97,10 @@ def test_paged_decode_compiles(one_chip, fused):
     def decode(q, kp, vp, table, s_q, s_k, s_v, lens):
         return op(q, kp, vp, table, s_q, s_k, s_v, lens, EXP_LUT, RECIP_LUT,
                   cfg=CFG, impl="pallas")
-    _compile(decode, one_chip, ((B, HQ, D), q_dtype), POOL, POOL, TABLE,
-             ((B,), jnp.float32), SCALAR, SCALAR, LENS)
+    kernel = ("splitmax_decode_fused_paged_pallas" if fused
+              else "splitmax_decode_paged_pallas")
+    _compile(decode, kernel, one_chip, ((B, HQ, D), q_dtype), POOL, POOL,
+             TABLE, ((B,), jnp.float32), SCALAR, SCALAR, LENS)
 
 
 @pytest.mark.parametrize("fused", [True, False])
@@ -101,7 +111,9 @@ def test_dense_decode_compiles(one_chip, fused):
     def decode(q, k, v, s_q, s_k, s_v, lens):
         return op(q, k, v, s_q, s_k, s_v, lens, EXP_LUT, RECIP_LUT, cfg=CFG,
                   impl="pallas")
-    _compile(decode, one_chip, ((B, HQ, D), q_dtype), DENSE_CACHE,
+    kernel = ("splitmax_decode_fused_pallas" if fused
+              else "splitmax_decode_pallas")
+    _compile(decode, kernel, one_chip, ((B, HQ, D), q_dtype), DENSE_CACHE,
              DENSE_CACHE, ((B,), jnp.float32), SCALAR, SCALAR, LENS)
 
 
@@ -114,15 +126,15 @@ def test_verify_compiles(one_chip, paged):
             return ops.splitmax_decode_fused_verify_paged(
                 q, kp, vp, table, s_q, s_k, s_v, lens, EXP_LUT, RECIP_LUT,
                 cfg=CFG, impl="pallas")
-        _compile(verify, one_chip, q, POOL, POOL, TABLE, s_q, SCALAR, SCALAR,
-                 LENS)
+        _compile(verify, "splitmax_decode_fused_verify_paged_pallas",
+                 one_chip, q, POOL, POOL, TABLE, s_q, SCALAR, SCALAR, LENS)
     else:
         def verify(q, k, v, s_q, s_k, s_v, lens):
             return ops.splitmax_decode_fused_verify(
                 q, k, v, s_q, s_k, s_v, lens, EXP_LUT, RECIP_LUT, cfg=CFG,
                 impl="pallas")
-        _compile(verify, one_chip, q, DENSE_CACHE, DENSE_CACHE, s_q, SCALAR,
-                 SCALAR, LENS)
+        _compile(verify, "splitmax_decode_fused_verify_pallas", one_chip, q,
+                 DENSE_CACHE, DENSE_CACHE, s_q, SCALAR, SCALAR, LENS)
 
 
 @pytest.mark.parametrize("requant", [False, True])
@@ -130,5 +142,5 @@ def test_int8_matmul_compiles(one_chip, requant):
     def gemm(x, w, mult):
         return ops.int8_matmul(x, w, mult if requant else None,
                                impl="pallas")
-    _compile(gemm, one_chip, ((512, 2048), jnp.int8),
+    _compile(gemm, "int8_matmul_pallas", one_chip, ((512, 2048), jnp.int8),
              ((2048, 2048), jnp.int8), SCALAR)
