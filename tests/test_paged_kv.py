@@ -83,27 +83,49 @@ def test_gather_kv_addressing(rng):
 # ------------------------- kernel: table gather -----------------------------
 
 PAGED_GRID = [
-    # b, hq, hkv, mb (blocks/slot), d, bk
-    (2, 4, 2, 2, 64, 128),
-    (1, 8, 1, 4, 128, 64),
-    (3, 6, 6, 3, 64, 128),
+    # b, hq, hkv, mb (blocks/slot), d, bk, lens (None: drawn at random)
+    (2, 4, 2, 2, 64, 128, None),
+    (1, 8, 1, 4, 128, 64, None),
+    (3, 6, 6, 3, 64, 128, None),
+    # 32-token pages, so a grid step takes 16 table entries (512 positions).
+    # Group 1, Hkv 8: length 1, a page and a chunk boundary and one past it,
+    # the full table; 37 entries are not a whole number of chunks.
+    (5, 8, 8, 37, 128, 32, (1, 32, 512, 513, 37 * 32)),
+    # group 4, Hkv 2, D 64: the short slot's last chunk is dead
+    (3, 8, 2, 20, 64, 32, (511, 640, 64)),
+    # group 8, Hkv 1: one chunk, a slot that ends inside its first page
+    (2, 8, 1, 16, 128, 32, (512, 31)),
 ]
 
 
 @pytest.mark.parametrize("shape", PAGED_GRID)
 @pytest.mark.parametrize("window", [None, 64])
 def test_paged_decode_matches_ref(rng, shape, window):
-    b, hq, hkv, mb, d, bk = shape
+    """The block-table kernel against the oracle, and bitwise against the
+    dense kernel on the gathered cache at ``block_k`` = the page: both add
+    page by page in table order.  The XLA twin sums every position in one
+    product, so it is bitwise only while each sum is an exact f32 integer:
+    with a 64-token window here.  Where the lengths are given, table rows
+    end in the trash block past each slot's length, as the engine leaves
+    them, and the trash block holds garbage."""
+    b, hq, hkv, mb, d, bk, given = shape
     num_blocks = 1 + b * mb
     q1 = rng.integers(-128, 128, (b, hq, d)).astype(np.int8)
+    qf = jnp.asarray(rng.normal(0, 0.5, (b, hq, d)), jnp.float32)
     k_pages = jnp.asarray(
         rng.integers(-128, 128, (num_blocks, hkv, bk, d)), jnp.int8)
     v_pages = jnp.asarray(
         rng.integers(-128, 128, (num_blocks, hkv, bk, d)), jnp.int8)
     # non-trivial table: slots own a shuffled set of non-trash blocks
-    perm = rng.permutation(np.arange(1, num_blocks))
-    table = jnp.asarray(perm.reshape(b, mb), jnp.int32)
-    lens = jnp.asarray(rng.integers(1, mb * bk + 1, (b,)), jnp.int32)
+    perm = rng.permutation(np.arange(1, num_blocks)).reshape(b, mb)
+    if given is None:
+        lens = rng.integers(1, mb * bk + 1, (b,))
+    else:
+        lens = np.asarray(given)
+        perm[np.arange(mb)[None, :] >= -(-lens[:, None] // bk)] = (
+            paged_kv.TRASH_BLOCK)
+    table = jnp.asarray(perm, jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
     args = (q1, k_pages, v_pages, table, *SCALES, lens, EXP_LUT, RECIP_LUT)
     ref = ops.splitmax_decode_paged(*args, cfg=CFG, impl="ref",
                                     window=window)
@@ -115,6 +137,60 @@ def test_paged_decode_matches_ref(rng, shape, window):
                                rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(xla), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+    k_dense = paged_kv.gather_kv(k_pages, table)
+    v_dense = paged_kv.gather_kv(v_pages, table)
+    for name, paged_op, dense_op, q in (
+            ("composed", ops.splitmax_decode_paged, ops.splitmax_decode, q1),
+            ("fused", ops.splitmax_decode_fused_paged,
+             ops.splitmax_decode_fused, qf)):
+        rest = (*SCALES, lens, EXP_LUT, RECIP_LUT)
+        paged = paged_op(q, k_pages, v_pages, table, *rest, cfg=CFG,
+                         window=window, impl="interpret")
+        dense = dense_op(q, k_dense, v_dense, *rest, cfg=CFG, window=window,
+                         block_k=bk, impl="interpret")
+        np.testing.assert_array_equal(np.asarray(paged), np.asarray(dense),
+                                      err_msg=name)
+        if window is not None:
+            twin = paged_op(q, k_pages, v_pages, table, *rest, cfg=CFG,
+                            window=window, impl="xla")
+            np.testing.assert_array_equal(np.asarray(paged),
+                                          np.asarray(twin), err_msg=name)
+
+
+@pytest.mark.parametrize("window", [None, 64])
+def test_fetch_table_copies_each_live_page_once(rng, window):
+    """The paged decode kernel's page inputs copy a pool block only when
+    their block changes from one grid step to the next.  Every live table
+    entry must name its own block, and after the first step an input copies
+    only a block that is live for it in the current slot: each live page is
+    fetched at most once, and a dead entry never costs a copy."""
+    from repro.kernels.splitmax_decode import _fetch_table, _live_pages
+    bk, pages, mb = 32, 16, 37
+    n_chunks = -(-mb // pages)
+    lens = np.asarray([0, 1, 100, 512, 513, 40, 3, mb * bk])
+    table = rng.integers(1, 1000, (len(lens), mb))
+    fetch = np.asarray(_fetch_table(jnp.asarray(table, jnp.int32),
+                                    jnp.asarray(lens, jnp.int32), window, bk,
+                                    pages, n_chunks))
+    assert fetch.shape == (len(lens), n_chunks * pages)
+    held, copies, live_total = None, 0, 0
+    for s, length in enumerate(lens):
+        lo, hi = (int(x) for x in _live_pages(int(length), window, bk, mb))
+        live = range(lo, hi)
+        live_total += len(live)
+        for c in range(n_chunks):
+            now = fetch[s, c * pages:(c + 1) * pages]
+            for i in range(pages):
+                j = c * pages + i
+                if j in live:
+                    assert now[i] == table[s, j], (s, j)
+                if held is not None and now[i] != held[i]:
+                    copies += 1
+                    assert now[i] in {table[s, x] for x in live
+                                      if x % pages == i}, (s, c, i)
+            held = now
+    assert copies <= live_total
 
 
 def test_paged_ref_equals_dense_ref_on_gathered_cache(rng):

@@ -1,8 +1,9 @@
 """Compile the serving path's Pallas kernels for a described TPU v5e.
 
 Nothing runs here: each test lowers one kernel at TinyLlama-1.1B widths
-(Hq=32, Hkv=4, head_dim=64, a paged pool of 32-token blocks) for a v5e chip
-that is described, not attached, and checks that Mosaic emitted the kernel
+(Hq=32, Hkv=4, head_dim=64, a paged pool of 32-token blocks), and the paged
+decode also at the benchmark's OLMo-1B and Mistral-NeMo shapes, for a v5e
+chip that is described, not attached, and checks that Mosaic emitted the kernel
 (``tpu_custom_call``).  Interpret mode cannot see what the chip's compiler
 refuses — unsupported shape casts, int32 operands on the MXU, tiles that do
 not fit VMEM — so these compiles guard the kernels between chip runs.
@@ -89,18 +90,34 @@ LENS = ((B,), jnp.int32)
 DENSE_CACHE = ((B, HKV, MAX_BLOCKS * BLOCK_K, D), jnp.int8)
 
 
-@pytest.mark.parametrize("fused", [True, False])
-def test_paged_decode_compiles(one_chip, fused):
+# the benchmark's configurations, as served: (B, Hq, Hkv, D, pool blocks,
+# table entries per slot), 32-token pages
+PAGED_SHAPES = {
+    "tinyllama": (B, HQ, HKV, D, 1 + B * MAX_BLOCKS, MAX_BLOCKS),
+    "olmo-1b": (32, 16, 16, 128, 2049, 64),
+    "mistral-nemo-12b-4l": (24, 32, 8, 128, 10_777, 449),
+}
+
+
+@pytest.mark.parametrize(
+    "shape,fused",
+    [(name, fused) for name in PAGED_SHAPES for fused in (True, False)],
+    ids=[str(fused) if name == "tinyllama" else f"{name}-{fused}"
+         for name in PAGED_SHAPES for fused in (True, False)])
+def test_paged_decode_compiles(one_chip, shape, fused):
+    b, hq, hkv, d, num_blocks, max_blocks = PAGED_SHAPES[shape]
     op = ops.splitmax_decode_fused_paged if fused else ops.splitmax_decode_paged
     q_dtype = jnp.bfloat16 if fused else jnp.int8
+    pool = ((num_blocks, hkv, BLOCK_K, d), jnp.int8)
 
     def decode(q, kp, vp, table, s_q, s_k, s_v, lens):
         return op(q, kp, vp, table, s_q, s_k, s_v, lens, EXP_LUT, RECIP_LUT,
                   cfg=CFG, impl="pallas")
     kernel = ("splitmax_decode_fused_paged_pallas" if fused
               else "splitmax_decode_paged_pallas")
-    _compile(decode, kernel, one_chip, ((B, HQ, D), q_dtype), POOL, POOL,
-             TABLE, ((B,), jnp.float32), SCALAR, SCALAR, LENS)
+    _compile(decode, kernel, one_chip, ((b, hq, d), q_dtype), pool, pool,
+             ((b, max_blocks), jnp.int32), ((b,), jnp.float32), SCALAR,
+             SCALAR, ((b,), jnp.int32))
 
 
 @pytest.mark.parametrize("fused", [True, False])
