@@ -5,10 +5,12 @@
 
 One process, one chip.  The cell (``BENCHMARK.json``'s ``workloads``) names
 a configuration (``configs/<name>.json``) and a traffic mix
-(``traffic/<cell>.json``).  Set-up makes the weights on the device from the
-seed, builds the queue, compiles every shape the window uses and fills the
-slots; then ``--seconds`` of serving are measured; then the served tokens
-are checked against the float32 reference (``reference.py``).
+(``traffic/<cell>.json``); the configuration names its model family
+(``families/<family>.py``: weights, reference and counts).  Set-up makes
+the weights on the device from the seed, builds the queue, compiles every
+shape the window uses and fills the slots; then ``--seconds`` of serving
+are measured; then the served tokens are checked against the family's
+float32 reference.
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
 per-layer ones from a profiler trace of the window.  Earlier lines on

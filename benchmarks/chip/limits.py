@@ -4,13 +4,14 @@
         --seconds <s> [--control-seeds 1,2,3]
 
 In one process, for each seed: one run of the cell as ``bench.py`` makes it
-(set-up, a window at the cell's own load), then the reference over the
-sampled served tokens, and for the control seeds the control over the same
-prompts and tokens (``reference.py``, ``control=True``).  Prints one JSON
-line per seed with the widest and mean gaps of both, and the verdict of the
-cell's own limits on each: ``correct`` for the program, ``ctrl_correct``
-for the control put in its place (it should read false).  The benchmark's
-own runs never run the control.
+(set-up, a window at the cell's own load), then the reference of the
+configuration's family over the sampled served tokens, and for the control
+seeds the control over the same prompts and tokens (the family's
+``compare``, ``control=True``).  Prints one JSON line per seed with the
+widest and mean gaps of both, and the verdict of the cell's own limits on
+each: ``correct`` for the program, ``ctrl_correct`` for the control put in
+its place (it should read false).  The benchmark's own runs never run the
+control.
 """
 from __future__ import annotations
 

@@ -38,6 +38,9 @@ class Model:
     @classmethod
     def from_config(cls, conf: Dict) -> "Model":
         m = conf["model"]
+        sections = sorted(k for k, v in m.items() if isinstance(v, dict))
+        if sections:
+            raise ValueError(f"a dense model has no sections: {sections}")
         if m["act"] != "silu":
             raise ValueError("the reference implements SwiGLU only")
         return cls(**{f.name: (conf["norm_eps"] if f.name == "norm_eps"

@@ -8,10 +8,14 @@ stays full: an offline batch job.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
+import importlib.util
 import json
 import pathlib
 import shutil
+import typing
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -20,12 +24,12 @@ import numpy as np
 import engine_proxy as ep
 import metric_readers
 import model_weights as mw
-import reference
 import trace_reduce
 import workload
 
 HERE = pathlib.Path(__file__).resolve().parent
 RUN_DIR = HERE / ".run"
+FAMILIES = HERE / "families"
 
 
 # ---- compilations inside the window ------------------------------------
@@ -59,20 +63,79 @@ def compile_watch() -> CompileWatch:
     return _WATCH
 
 
-# ---- the program's configuration --------------------------------------
+# ---- the model family and the program's configuration ------------------
+
+def program_family(conf: Dict) -> str:
+    """The family of the program's own entry for ``conf['program']``."""
+    from repro.configs import get_arch
+    return get_arch(conf["program"]).config.family
+
+
+@functools.lru_cache(maxsize=None)
+def _load_family(path: pathlib.Path) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(f"family_{path.stem}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family(conf: Dict) -> ModuleType:
+    """The module of the family the configuration states,
+    ``families/<conf['family']>.py``: its weights, reference and counts.
+
+    The file states the family, not the program, so that a change to the
+    program cannot switch the reference that checks it; a run whose
+    program is of another family is refused."""
+    name = conf["family"]
+    path = FAMILIES / f"{name}.py"
+    if not path.is_file():
+        known = sorted(p.stem for p in FAMILIES.glob("*.py"))
+        raise ValueError(f"no model family {name!r}: {FAMILIES} holds "
+                         f"{known}")
+    program = program_family(conf)
+    if program != name:
+        raise ValueError(f"the configuration states family {name!r}, the "
+                         f"program's {conf['program']!r} is {program!r}")
+    return _load_family(path)
+
+
+def _section(field: str, base, value: Dict):
+    """``value`` set over the program's own ``base`` for a field that holds
+    a dataclass (``moe``, ``ssm``); every key must be one of its fields."""
+    hint = typing.get_type_hints(type(base))[field]
+    cls = next((t for t in typing.get_args(hint) or (hint,)
+                if dataclasses.is_dataclass(t)), None)
+    if cls is None:
+        raise ValueError(f"model key {field!r} takes no section")
+    unknown = set(value) - {f.name for f in dataclasses.fields(cls)}
+    if unknown:
+        raise ValueError(f"unknown keys in section {field!r}: "
+                         f"{sorted(unknown)}")
+    own = getattr(base, field)
+    return cls(**value) if own is None else dataclasses.replace(own, **value)
+
 
 def program_config(conf: Dict):
     """The program's ModelConfig: its own entry for ``conf['program']``
-    with every key of ``conf['model']`` set as the file states it."""
+    with every key of ``conf['model']`` set as the file states it; a
+    section (a dict) is set key by key over the program's own value."""
     from repro.configs import get_arch
-    return get_arch(conf["program"]).config.replace(**conf["model"])
+    base = get_arch(conf["program"]).config
+    model = {k: _section(k, base, v) if isinstance(v, dict) else v
+             for k, v in conf["model"].items()}
+    return base.replace(**model)
 
 
-def make_params(seed: int, m: mw.Model, pcfg):
+def make_params(seed: int, m, pcfg,
+                program_params: Callable = mw.program_params):
+    """The program's parameters from the seed, made on the device in one
+    jitted call by a family's ``program_params`` (the dense one's unless
+    another is given)."""
     from repro.launch import steps as st
     key = mw.base_key(seed)
     shapes = jax.eval_shape(st.init_params_fn(pcfg), jax.random.PRNGKey(0))
-    params = jax.jit(lambda k: mw.program_params(k, m, shapes))(key)
+    params = jax.jit(lambda k: program_params(k, m, shapes))(key)
     return jax.block_until_ready(params)
 
 
@@ -81,7 +144,8 @@ def make_params(seed: int, m: mw.Model, pcfg):
 @dataclasses.dataclass
 class Run:
     cell: str
-    m: mw.Model
+    family: ModuleType                # families/<name>.py: counts of ``m``
+    m: object                         # the family's Model
     peaks: Dict[str, float]
     window_s: float
     decode_calls: List[dict]          # in the window
@@ -153,9 +217,10 @@ def run_cell(cell: str, traffic: Dict, conf: Dict, *, seed: int,
     from repro.launch import scheduler as sched
     from repro.launch import serve
 
-    m = mw.Model.from_config(conf)
+    fam = family(conf)
+    m = fam.Model.from_config(conf)
     pcfg = program_config(conf)
-    params = make_params(seed, m, pcfg)
+    params = make_params(seed, m, pcfg, fam.program_params)
     queue = workload.make_queue(traffic, m.vocab_size, seed)
     engine = serve.make_engine(params, pcfg, queue.prompts,
                                slots=traffic["slots"],
@@ -210,7 +275,8 @@ def run_cell(cell: str, traffic: Dict, conf: Dict, *, seed: int,
     finished = [rid for rid, r in proxy.requests.items()
                 if r.t_release is not None and len(r.decodes) + 1 >= r.gen]
     window_s = proxy.t_close - proxy.t_open
-    run = Run(cell=cell, m=m, peaks=peaks, window_s=t_layer - proxy.t_open,
+    run = Run(cell=cell, family=fam, m=m, peaks=peaks,
+              window_s=t_layer - proxy.t_open,
               decode_calls=calls, admissions=admissions,
               admit_span_s=admit_s, itl_ms=layer_itl)
     out = Outcome(setup_s=proxy.t_open - t_start, window_s=window_s,
@@ -255,12 +321,14 @@ def sample(out: Outcome, traffic: Dict, seed: int) -> List[int]:
 
 def check(out: Outcome, traffic: Dict, conf: Dict, seed: int, *,
           control: bool = False) -> Dict[str, np.ndarray]:
-    """Reference gaps over the sampled requests' served tokens."""
-    m = mw.Model.from_config(conf)
+    """Reference gaps over the sampled requests' served tokens, by the
+    reference of the configuration's family."""
+    fam = family(conf)
+    m = fam.Model.from_config(conf)
     gaps, ctrl = [], []
     for rid in sample(out, traffic, seed):
-        r = reference.compare(seed, m, out.queue.prompts[rid],
-                              out.served[rid], control=control)
+        r = fam.compare(seed, m, out.queue.prompts[rid], out.served[rid],
+                        control=control)
         gaps.append(r["gap"])
         if control:
             ctrl.append(r["ctrl_gap"])

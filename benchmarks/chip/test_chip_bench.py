@@ -29,7 +29,8 @@ import trace_reduce  # noqa: E402
 import workload  # noqa: E402
 
 TINY_CONF = {
-    "name": "tiny", "program": "mistral_nemo_12b", "norm_eps": 1e-6,
+    "name": "tiny", "program": "mistral_nemo_12b", "family": "dense",
+    "norm_eps": 1e-6,
     "model": {"n_layers": 2, "d_model": 128, "n_heads": 4, "n_kv_heads": 2,
               "head_dim": 32, "d_ff": 256, "vocab_size": 512,
               "norm": "rmsnorm", "act": "silu", "rope_theta": 1e4,
