@@ -125,7 +125,7 @@ def main(argv=None) -> int:
                                "idle_gaps": [list(x) for x in
                                              t.idle_by_span]}
         log(f"trace: module seconds {t.module_s}, executions "
-            f"{t.module_n}, kernel seconds {t.kernel_s}")
+            f"{t.module_n}, kernel seconds by name {t.kernel_s}")
     result["checks"] = checks
     for name, c in checks.items():
         log(f"check {name}: {c['value']!r} (limit {c['limit']!r})")

@@ -11,7 +11,13 @@ records, on the host clock and without ever waiting on a device value:
   * the token array each decode call receives: row ``s`` is the token the
     request in slot ``s`` was served last, so reading the arrays after the
     window recovers every served token but each request's last;
-  * the pool's live blocks at each decode call.
+  * the pool's live blocks at each decode call;
+  * the program's counters, where the engine has a ``counters(cache)``
+    method: the program's cumulative counts on the device (a ``Dict[str,
+    jax.Array | int]`` the engine keeps in its own state, valid after its
+    next call: copies, where that call donates the state), taken at the
+    call that opens the window and at the one that closes it, and read
+    from the device only after the window (:meth:`window_counters`).
 
 The window opens at the first decode call after ``warmup`` (every slot is
 filled by then) and closes at the first engine call ``seconds`` later, where
@@ -86,6 +92,9 @@ class EngineProxy(base.CacheEngine):
         self.slot_req: List[Optional[Request]] = [None] * engine.slots
         self.live_lens = np.zeros((engine.slots,), np.int64)
         self.admitted = 0
+        self._counters = getattr(engine, "counters", None)
+        self.counts_open: Optional[Dict] = None
+        self.counts_close: Optional[Dict] = None
 
     # ---- attributes the scheduler reads from its engine ----------------
     slots = property(lambda self: self.engine.slots)
@@ -100,10 +109,12 @@ class EngineProxy(base.CacheEngine):
     def is_open(self) -> bool:
         return self.t_open is not None
 
-    def _enter(self, kind: str) -> float:
+    def _enter(self, kind: str, cache) -> float:
         now = time.perf_counter()
         if self.t_open is not None and now - self.t_open >= self.seconds:
             self.t_close = now
+            if self._counters is not None:
+                self.counts_close = self._counters(cache)
             raise WindowClosed()
         if (self.mark_after is not None and self.t_open is not None
                 and self.t_mark is None
@@ -113,6 +124,8 @@ class EngineProxy(base.CacheEngine):
                 self.on_mark()
             now = time.perf_counter()
         if kind == "decode" and self.t_open is None:
+            if self._counters is not None:
+                self.counts_open = self._counters(cache)
             if self.on_open is not None:
                 self.on_open()
             now = self.t_open = time.perf_counter()
@@ -156,7 +169,7 @@ class EngineProxy(base.CacheEngine):
         return self.engine.admission_need(rid)
 
     def admit(self, cache, slot: int, rid: int):
-        now = self._enter("admit")
+        now = self._enter("admit", cache)
         with self._span("admit"):
             out = self.engine.admit(cache, slot, rid)
         req = Request(rid, slot, len(self.prompts[rid]), now, self.gens[rid])
@@ -177,12 +190,12 @@ class EngineProxy(base.CacheEngine):
         return self.engine.grow_blocks(slot, n)
 
     def grow_write(self, cache, slot: int, idx: int, block: int):
-        self._enter("grow")
+        self._enter("grow", cache)
         with self._span("grow"):
             return self.engine.grow_write(cache, slot, idx, block)
 
     def decode(self, tokens, cache):
-        now = self._enter("decode")
+        now = self._enter("decode", cache)
         idx = len(self.decode_calls)
         occupied = [s for s, r in enumerate(self.slot_req) if r is not None]
         self.live_lens[occupied] += 1          # this call writes one more
@@ -200,7 +213,7 @@ class EngineProxy(base.CacheEngine):
             return self.engine.decode(tokens, cache)
 
     def release(self, cache, slot: int):
-        now = self._enter("release")
+        now = self._enter("release", cache)
         req = self.slot_req[slot]
         if req is not None:
             req.t_release = now
@@ -227,3 +240,12 @@ class EngineProxy(base.CacheEngine):
         rows = np.stack(jax.device_get(self.token_arrays))
         return {rid: rows[req.decodes, req.slot]
                 for rid, req in self.requests.items()}
+
+    def window_counters(self) -> Dict[str, float]:
+        """name -> what the program counted from the window's opening to
+        its close, read from the device once the window has closed; empty
+        for an engine that keeps no counters."""
+        if self.counts_open is None or self.counts_close is None:
+            return {}
+        start, end = jax.device_get((self.counts_open, self.counts_close))
+        return {name: float(end[name]) - float(start[name]) for name in end}

@@ -152,6 +152,10 @@ class Run:
     admissions: List[tuple]           # (t, prompt_len) in the window
     admit_span_s: float               # admit entry -> next call, summed
     itl_ms: List[float]               # token gaps in the window
+    # the program's own counts (engine ``counters``), closing values less
+    # opening ones: over the whole window, also in a traced run; {} for an
+    # engine that keeps none.  A reader takes ``counters.get(name)``.
+    counters: Dict[str, float]
     trace: Optional[trace_reduce.TraceSummary] = None
 
 
@@ -268,6 +272,7 @@ def run_cell(cell: str, traffic: Dict, conf: Dict, *, seed: int,
     stats = jax.devices()[0].memory_stats() or {}
     peak = stats.get("peak_bytes_in_use")
     served = proxy.served_tokens()
+    counters = proxy.window_counters()
     _, _, _, tokens, itl, failed = _window_record(proxy, proxy.t_close)
     t_layer = proxy.t_mark or proxy.t_close
     calls, admissions, admit_s, _, layer_itl, _ = _window_record(proxy,
@@ -278,7 +283,7 @@ def run_cell(cell: str, traffic: Dict, conf: Dict, *, seed: int,
     run = Run(cell=cell, family=fam, m=m, peaks=peaks,
               window_s=t_layer - proxy.t_open,
               decode_calls=calls, admissions=admissions,
-              admit_span_s=admit_s, itl_ms=layer_itl)
+              admit_span_s=admit_s, itl_ms=layer_itl, counters=counters)
     out = Outcome(setup_s=proxy.t_open - t_start, window_s=window_s,
                   attempted=proxy.admitted, failed=failed, tokens=tokens,
                   itl_ms=itl, compiles=watch.count,

@@ -96,6 +96,56 @@ def test_traffic_is_seeded(cell):
     assert all(int(p.max()) < vocab for p in a.prompts)
 
 
+SKEW = {"topics": 4, "ids_per_topic": 64, "zipf": 1.2}
+
+
+def test_token_skew_draws_each_prompt_from_one_topic():
+    traffic = workload.load_json("traffic", "olmo-1b.decode")
+    vocab = workload.load_json("configs", "olmo-1b")["model"]["vocab_size"]
+    small = dict(traffic, requests=traffic["slots"] + 60)
+    skewed = dict(small, token_skew=SKEW)
+    seed = 2**31 + 13
+    a = workload.make_queue(skewed, vocab, seed)
+    b = workload.make_queue(skewed, vocab, seed)
+    c = workload.make_queue(skewed, vocab, seed + 1)
+    assert all(np.array_equal(x, y) for x, y in zip(a.prompts, b.prompts))
+    assert any(not np.array_equal(x, y) for x, y in zip(a.prompts, c.prompts))
+    # the cell's sizes, request by request, as without the key
+    plain = workload.make_queue(small, vocab, seed)
+    sizes = lambda q: [(len(p), g) for p, g in zip(q.prompts, q.gens)]
+    assert sizes(a) == sizes(plain)
+    assert all(p.dtype == np.int32 and 0 <= p.min() and p.max() < vocab
+               for p in a.prompts)
+    # a prompt's most frequent id is its topic's first rank: prompts of
+    # one topic draw from that topic's ids alone, and there are at most
+    # as many topics as the key says
+    by_topic = {}
+    for p in a.prompts:
+        by_topic.setdefault(int(np.bincount(p).argmax()), []).append(p)
+    assert 1 < len(by_topic) <= SKEW["topics"]
+    share = workload.zipf_shares(SKEW["ids_per_topic"], SKEW["zipf"])[0]
+    for top, prompts in by_topic.items():
+        ids = np.concatenate(prompts)
+        assert len(np.unique(ids)) <= SKEW["ids_per_topic"]
+        # the first rank holds its Zipf share, to within sampling error
+        sigma = (share * (1 - share) / ids.size) ** 0.5
+        assert abs(np.mean(ids == top) - share) < 5 * sigma
+
+
+@pytest.mark.parametrize("skew,match", [
+    (dict(SKEW, topics=0), "topics must be a whole number >= 1"),
+    (dict(SKEW, ids_per_topic=0), r"ids_per_topic must be .* in \[1, 512\]"),
+    (dict(SKEW, ids_per_topic=513), r"ids_per_topic must be .* in \[1, 512\]"),
+    (dict(SKEW, zipf=0.0), "zipf must be > 0"),
+    ({"topics": 4, "zipf": 1.2}, "takes exactly the keys"),
+    (dict(SKEW, hot_share=0.5), "takes exactly the keys"),
+], ids=["no_topic", "no_ids", "ids_over_vocab", "flat", "missing_key",
+        "unknown_key"])
+def test_token_skew_refuses_what_it_cannot_draw(skew, match):
+    with pytest.raises(ValueError, match=match):
+        workload.make_queue(dict(TINY_TRAFFIC, token_skew=skew), 512, 1)
+
+
 def test_counts_by_hand():
     m = mw.Model(n_layers=2, d_model=8, n_heads=2, n_kv_heads=1,
                  head_dim=4, d_ff=16, vocab_size=10, norm="rmsnorm",
@@ -251,6 +301,40 @@ def test_traced_run_checks_the_whole_window(monkeypatch):
     assert checks["tokens_compared"]["value"] >= traffic["sample_tokens"]
 
 
+class _Counting:
+    """The paged engine with a program counter of its decode calls, kept
+    cumulative in its own state, one on the device and one on the host."""
+
+    def __init__(self, engine):
+        self.__dict__["_e"] = engine
+        self.__dict__["n"] = 0
+
+    def __getattr__(self, name):
+        return getattr(self._e, name)
+
+    def decode(self, tokens, cache):
+        self.__dict__["n"] += 1
+        return self._e.decode(tokens, cache)
+
+    def counters(self, cache):
+        return {"decode_calls": jnp.asarray(self.n, jnp.int32),
+                "decode_calls_on_host": self.n}
+
+
+@pytest.mark.parametrize("counting", [True, False], ids=["counters",
+                                                         "none"])
+def test_program_counters_cover_the_window(counting):
+    out = runner.run_cell("tiny", TINY_TRAFFIC, TINY_CONF, seed=2**31 + 23,
+                          seconds=1.0, trace=False,
+                          t_start=time.perf_counter(), peaks=PEAKS,
+                          wrap_engine=_Counting if counting else None)
+    calls = len(out.run.decode_calls)
+    assert calls > 0
+    want = ({"decode_calls": calls, "decode_calls_on_host": calls}
+            if counting else {})
+    assert out.run.counters == want
+
+
 class _Broken:
     """The paged engine with its decode step broken underneath."""
 
@@ -305,6 +389,17 @@ def test_compile_watch_counts_only_when_armed():
 
 # ---- trace reduction -----------------------------------------------------
 
+def _hlo_kernel(name, n):
+    """A Pallas kernel's operation as the trace names it: its whole HLO
+    instruction, named after the kernel's ``name=``."""
+    return (f"%{name}.{n} = f32[8,128]{{1,0}} custom-call(f32[8,128]{{1,0}} "
+            f'%param.{n}), custom_call_target="tpu_custom_call"')
+
+
+DECODE_KERNEL = _hlo_kernel("splitmax_decode_fused_paged_pallas", 7)
+PREFILL_KERNEL = _hlo_kernel("splitmax_attention_pallas", 2)
+
+
 def _synthetic_planes():
     ms = 1_000_000
     return {
@@ -319,12 +414,12 @@ def _synthetic_planes():
                             ("jit_greedy(3)", 90 * ms, 91 * ms),
                             ("jit_decode_step(1)", 95 * ms, 120 * ms)],
             "XLA Ops": [("fusion.1", 10 * ms, 20 * ms),
-                        ("custom-call.7", 20 * ms, 30 * ms),
+                        (DECODE_KERNEL, 20 * ms, 30 * ms),
                         ("while.4", 55 * ms, 85 * ms),
-                        ("custom-call.2", 55 * ms, 65 * ms),
+                        (PREFILL_KERNEL, 55 * ms, 65 * ms),
                         ("fusion.9", 60 * ms, 85 * ms),
                         ("fusion.3", 90 * ms, 91 * ms),
-                        ("custom-call.7", 95 * ms, 120 * ms)],
+                        (DECODE_KERNEL, 95 * ms, 120 * ms)],
         },
     }
 
@@ -337,11 +432,13 @@ def test_trace_reduction_on_a_synthetic_trace():
     assert t.module_s == pytest.approx({"decode": 0.025, "prefill": 0.030,
                                         "other": 0.001})
     assert t.module_n == {"decode": 2, "prefill": 1, "other": 1}
-    assert t.kernel_s == pytest.approx({"decode": 0.015, "prefill": 0.010})
+    assert t.kernel_s == pytest.approx({
+        "decode/splitmax_decode_fused_paged_pallas": 0.015,
+        "prefill/splitmax_attention_pallas": 0.010})
     # ops are named with the program that ran them; the loop that holds
     # the prefill's two operations is not named
     assert [n for n, _ in t.top_ops[:2]] == ["prefill/fusion.9",
-                                             "decode/custom-call.7"]
+                                             "decode/" + DECODE_KERNEL]
     assert not any("while" in n for n, _ in t.top_ops)
     assert t.top_ops[1][1] == pytest.approx(0.015)
     # idle 0-10, 30-55, 85-90, 91-95 ms; the host's spans cover 5-6 of
@@ -364,13 +461,87 @@ def test_trace_reduction_on_a_recorded_trace():
     idle = sum(s for _, s in t.idle_by_span)
     assert t.busy_s + idle == pytest.approx(t.window_s, abs=1e-6)
     assert t.module_n["decode"] == 2
-    assert 0 < t.kernel_s["decode"] < t.module_s["decode"] <= t.busy_s
+    kernel = t.kernel_s["decode/splitmax_decode_fused_paged_pallas"]
+    assert 0 < kernel < t.module_s["decode"] <= t.busy_s
     # the loops are left out of the top operations, so no time is named
     # twice: what the top operations hold fits in the busy time
     assert not any("%while" in n for n, _ in t.top_ops)
     assert sum(s for _, s in t.top_ops) <= t.busy_s
     assert "splitmax_decode_fused_paged" in t.top_ops[0][0]
-    assert t.top_ops[0][1] == pytest.approx(t.kernel_s["decode"], rel=1e-5)
+    assert t.top_ops[0][1] == pytest.approx(kernel, rel=1e-5)
+
+
+def _layer_readings(planes):
+    """Every per-layer metric of ``olmo-1b.decode`` over a trace, with a
+    fixed record of the window's calls."""
+    run = runner.Run(
+        cell="olmo-1b.decode", family=runner.family(TINY_CONF),
+        m=mw.Model.from_config(TINY_CONF), peaks=PEAKS, window_s=0.1,
+        decode_calls=[{"t": 0.0, "slots": 4, "live_tokens": 4000,
+                       "live_blocks": 128, "pool_blocks": 256}] * 2,
+        admissions=[(0.05, 24)], admit_span_s=0.002, itl_ms=[20.0],
+        counters={}, trace=trace_reduce.summarize(planes))
+    return metric_readers.read_all(BENCH["per_layer"], run.cell, run)
+
+
+def test_a_second_kernel_leaves_the_attention_roofline():
+    """A second named kernel in the decode program, an operation that
+    takes the attention kernel's output and a buffer's allocation are
+    timed apart from the attention kernel, which its roofline reads."""
+    ms = 1_000_000
+    planes = _synthetic_planes()
+    ops = planes["/device:TPU:0"]["XLA Ops"]
+    assert ops[0] == ("fusion.1", 10 * ms, 20 * ms)
+    ops[0:1] = [
+        (_hlo_kernel("grouped_expert_matmul_pallas", 3), 10 * ms, 15 * ms),
+        ('%custom-call.4 = s8[8,128]{1,0} custom-call(), '
+         'custom_call_target="AllocateBuffer"', 15 * ms, 16 * ms),
+        ("%convert.5 = bf16[8,128]{1,0} convert(f32[8,128]{1,0} "
+         "%splitmax_decode_fused_paged_pallas.7)", 16 * ms, 20 * ms)]
+    t = trace_reduce.summarize(planes)
+    assert t.kernel_s == pytest.approx({
+        "decode/splitmax_decode_fused_paged_pallas": 0.015,
+        "decode/grouped_expert_matmul_pallas": 0.005,
+        "prefill/splitmax_attention_pallas": 0.010})
+    before = _layer_readings(_synthetic_planes())
+    assert {"kern.decode_attn_roofline",
+            "kern.prefill_attn_roofline"} <= set(before)
+    assert _layer_readings(planes) == before
+
+
+def _recorded(name):
+    raw = json.loads((HERE / "testdata" / name).read_text())
+    return {p: {ln: [tuple(ev) for ev in evs] for ln, evs in lines.items()}
+            for p, lines in raw.items()}
+
+
+@pytest.mark.parametrize("name,kernels", [
+    ("chat_trace_excerpt.json", {"decode/splitmax_decode_fused_paged_pallas",
+                                 "prefill/splitmax_attention_pallas"}),
+    ("longctx_trace_excerpt.json",
+     {"decode/splitmax_decode_fused_paged_pallas"}),
+])
+def test_recorded_kernels_are_timed_by_name(name, kernels):
+    """On one TPU v5e's trace each kernel's time is that of the operations
+    whose own instruction it is, clipped to the window; the operations that
+    take its output and the buffers' allocations are no kernel's."""
+    planes = _recorded(name)
+    t = trace_reduce.summarize(planes)
+    assert set(t.kernel_s) == kernels
+    a, b = planes["/host:CPU"]["bench"][0][1:]
+    assert planes["/host:CPU"]["bench"][0][0] == "bench.window"
+    ops = planes["/device:TPU:0"]["XLA Ops"]
+    for key, seconds in t.kernel_s.items():
+        kernel = key.split("/")[1]
+        own = sum(min(e, b) - max(s, a) for n, s, e in ops
+                  if n.startswith(f"%{kernel}.") and e > a and s < b)
+        assert seconds == pytest.approx(own / 1e9, rel=1e-9)
+    takers = [n for n, _, _ in ops if trace_reduce.kernel_name(n) is None
+              and any(f"%{k.split('/')[1]}." in n for k in t.kernel_s)]
+    assert any(n.startswith("%convert") for n in takers)
+    allocs = [n for n, _, _ in ops
+              if 'custom_call_target="AllocateBuffer"' in n]
+    assert allocs and not any(map(trace_reduce.kernel_name, allocs))
 
 
 def test_readers_exist_for_every_per_layer_metric():

@@ -94,9 +94,11 @@ def test_a_family_is_found_from_its_file_alone(tmp_path, monkeypatch):
 
 
 REFUSALS = {
-    # no families/moe.py yet: the message names the files there are
-    "unknown_family": (lambda: runner.family(MOE_SMOKE),
-                       r"no model family 'moe'.*\['dense'\]"),
+    # a name no file can hold, as no module can be named so: the message
+    # names the files there are
+    "unknown_family": (
+        lambda: runner.family(_with(MOE_SMOKE, family="no-such-family")),
+        r"no model family 'no-such-family'.*\[.*'dense'.*\]"),
     "family_not_the_programs": (
         lambda: runner.family(_with(MOE_SMOKE, family="dense")),
         r"states family 'dense', the program's 'deepseek_moe_16b' is "
@@ -120,6 +122,22 @@ def test_a_configuration_the_family_cannot_serve_is_refused(case):
     fn, match = REFUSALS[case]
     with pytest.raises(ValueError, match=match):
         fn()
+
+
+def test_a_moe_family_needs_its_file_alone(tmp_path, monkeypatch):
+    """The next family's file, beside the dense one, is all the harness
+    needs to find it; a configuration that states another family for the
+    same program is still refused."""
+    (tmp_path / "dense.py").write_text(
+        (runner.FAMILIES / "dense.py").read_text())
+    (tmp_path / "moe.py").write_text('"""A stub of the moe family."""\n')
+    monkeypatch.setattr(runner, "FAMILIES", tmp_path)
+    stub = runner.family(MOE_SMOKE)
+    assert pathlib.Path(stub.__file__) == tmp_path / "moe.py"
+    assert stub.__doc__ == "A stub of the moe family."
+    with pytest.raises(ValueError, match=r"states family 'dense', the "
+                       r"program's 'deepseek_moe_16b' is 'moe'"):
+        runner.family(_with(MOE_SMOKE, family="dense"))
 
 
 def test_program_config_builds_the_moe_section(tmp_path):

@@ -4,22 +4,28 @@ The traced run wraps its window in a host span ``bench.window`` and each
 engine call in ``bench.<kind>``.  The device plane's ``XLA Modules`` line
 says which jitted program ran when, and its ``XLA Ops`` line every
 operation; an operation is attributed to the module whose interval holds
-it.  Until the program names its programs and kernels, a module is
-classed by its jitted function's name (``decode_step``, ``prefill_step``)
-and a Pallas kernel is the custom call inside it.  An operation that holds
-others, as a ``while`` loop over the layers holds their operations, counts
-toward the device's busy time but is not itself named among the top
-operations: its time is theirs.
+it.  A module is classed by its jitted function's name (``decode_step``,
+``prefill_step``).  A Pallas kernel is an operation whose own instruction
+is a ``tpu_custom_call``, and is known by that instruction's name, which is
+the ``name=`` its ``pallas_call`` was given: its time is summed per module
+class and kernel, ``kernel_s["decode/splitmax_decode_fused_paged_pallas"]``,
+so that two kernels in one program are timed apart, and an operation that
+only takes a kernel's output, or allocates its buffer, is no kernel's
+time.  An operation that holds others, as a ``while`` loop over the layers
+holds their operations, counts toward the device's busy time but is not
+itself named among the top operations: its time is theirs.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
 import pathlib
+import re
 from typing import Dict, List, Optional, Tuple
 
 MODULE_CLASSES = (("decode", "decode_step"), ("prefill", "prefill_step"))
-KERNEL_MARKS = ("custom-call", "custom_call", "splitmax", "pallas")
+KERNEL_TARGET = 'custom_call_target="tpu_custom_call"'
+_HLO_SUFFIX = re.compile(r"\.\d+$")
 
 Interval = Tuple[int, int]
 
@@ -30,7 +36,7 @@ class TraceSummary:
     busy_s: float
     module_s: Dict[str, float]          # class -> device seconds
     module_n: Dict[str, int]            # class -> executions
-    kernel_s: Dict[str, float]          # class -> Pallas kernel seconds
+    kernel_s: Dict[str, float]          # "<class>/<kernel>" -> seconds
     top_ops: List[Tuple[str, float]]    # ("<class>/<op>", device seconds)
     idle_by_span: List[Tuple[str, float]]
 
@@ -65,9 +71,19 @@ def module_class(name: str) -> str:
     return "other"
 
 
-def is_kernel(op_name: str) -> bool:
-    low = op_name.lower()
-    return any(mark in low for mark in KERNEL_MARKS)
+def kernel_name(op_name: str) -> Optional[str]:
+    """The Pallas kernel an operation runs, or None.
+
+    An operation's name in the trace is its whole HLO instruction,
+    ``%<name>.<N> = <shape> custom-call(<operands>), custom_call_target=
+    "tpu_custom_call", ...``.  Its own instruction is the text before
+    `` = ``; without ``%`` and XLA's ``.N`` suffix that is the kernel's
+    ``name=``.  An operation that names a kernel only among its operands,
+    or a custom call of another target, runs no kernel."""
+    own, sep, rest = op_name.partition(" = ")
+    if not sep or KERNEL_TARGET not in rest:
+        return None
+    return _HLO_SUFFIX.sub("", own.lstrip("%"))
 
 
 def _clip(iv: Interval, win: Interval) -> Optional[Interval]:
@@ -131,8 +147,9 @@ def summarize(planes: Dict[str, Dict[str, list]],
         cls = (module_class(modules[mi][2])
                if mi < len(modules) and modules[mi][0] <= s else "other")
         per_op[f"{cls}/{n}"] += dt
-        if is_kernel(n) and cls != "other":
-            kernel_s[cls] += dt
+        kernel = kernel_name(n)
+        if kernel is not None:
+            kernel_s[f"{cls}/{kernel}"] += dt
     busy = _union(busy)
     busy_s = sum(b - a for a, b in busy) / 1e9
 

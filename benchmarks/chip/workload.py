@@ -16,7 +16,14 @@ holds the parameters of one general generator:
     window finished that many (the longest request first, then others
     drawn from the seed); ``min_compared``: the fewest a run may compare;
     ``gap_limit``: the limit of the widest gap;
-  * ``trace_seconds``: how much of the window a traced run profiles.
+  * ``trace_seconds``: how much of the window a traced run profiles;
+  * ``token_skew`` (optional): ``{"topics": T, "ids_per_topic": N,
+    "zipf": s}``.  Each of ``T`` topics is a subset of ``N`` ids of the
+    vocabulary, drawn from the seed in an order that ranks them; each
+    request draws one topic, and its prompt's ids follow a Zipf law of
+    exponent ``s`` over that topic's ``N`` ranks.  Skewed ids route
+    unevenly over experts, as real topics do; without the key ids are
+    uniform over the vocabulary.
 
 The requests that first fill the slots take their output lengths the same
 way over ``[1, hi]``, so slots free at a steady rate from the start, and
@@ -25,7 +32,7 @@ their prompt lengths in the block's shares (largest remainders).
 Every seed gets the same requests' sizes: the window's work, and so its
 times, must not change with the seed.  The seed deals the filling requests
 to the slots in an order of its own, and draws every token id (uniform over
-the configuration's vocabulary) and the weights.
+the configuration's vocabulary, or by ``token_skew``) and the weights.
 """
 from __future__ import annotations
 
@@ -92,6 +99,36 @@ def _fill_counts(block, slots: int):
     return [(n, k) for (n, _), k in zip(block, counts) if k]
 
 
+def zipf_shares(n: int, s: float) -> np.ndarray:
+    """The Zipf law of exponent ``s`` over ranks 1..n."""
+    w = np.arange(1, n + 1, dtype=np.float64) ** -s
+    return w / w.sum()
+
+
+def _skewed_ids(skew: Dict, vocab: int, lens: List[int],
+                rng: np.random.Generator) -> np.ndarray:
+    """Prompt ids by topic: ``topics`` ranked subsets of ``ids_per_topic``
+    ids, one topic a request, ranks by a Zipf law of exponent ``zipf``."""
+    keys = {"topics", "ids_per_topic", "zipf"}
+    if set(skew) != keys:
+        raise ValueError(f"token_skew takes exactly the keys {sorted(keys)}, "
+                         f"not {sorted(skew)}")
+    topics, per, s = skew["topics"], skew["ids_per_topic"], skew["zipf"]
+    if not (isinstance(topics, int) and topics >= 1):
+        raise ValueError(f"token_skew topics must be a whole number >= 1, "
+                         f"not {topics!r}")
+    if not (isinstance(per, int) and 1 <= per <= vocab):
+        raise ValueError(f"token_skew ids_per_topic must be a whole number "
+                         f"in [1, {vocab}], not {per!r}")
+    if not (isinstance(s, (int, float)) and s > 0):
+        raise ValueError(f"token_skew zipf must be > 0, not {s!r}")
+    ranked = np.stack([rng.choice(vocab, per, replace=False)
+                       for _ in range(topics)]).astype(np.int32)
+    topic = np.repeat(rng.integers(0, topics, len(lens)), lens)
+    rank = rng.choice(per, size=sum(lens), p=zipf_shares(per, s))
+    return ranked[topic, rank]
+
+
 def make_queue(traffic: Dict, vocab: int, seed: int) -> Queue:
     block = [(int(n), int(c)) for n, c in traffic["prompt_block"]]
     lo, hi = traffic["output"]
@@ -111,6 +148,9 @@ def make_queue(traffic: Dict, vocab: int, seed: int) -> Queue:
     gens[:slots] = [gens[i] for i in deal]
     if max(lens) + hi + 1 > traffic["max_len"]:
         raise ValueError("the longest request overruns max_len")
-    ids = rng.integers(0, vocab, sum(lens), dtype=np.int32)
+    if "token_skew" in traffic:
+        ids = _skewed_ids(traffic["token_skew"], vocab, lens, rng)
+    else:
+        ids = rng.integers(0, vocab, sum(lens), dtype=np.int32)
     prompts = np.split(ids, np.cumsum(lens)[:-1])
     return Queue(prompts=prompts, gens=[int(g) for g in gens])
