@@ -197,6 +197,19 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     return out.astype(in_dtype)
 
 
+def paged_append(k_pages: jax.Array, v_pages: jax.Array, blk: jax.Array,
+                 off: jax.Array, k_new: jax.Array, v_new: jax.Array,
+                 spec: AttentionSpec) -> Tuple[jax.Array, jax.Array]:
+    """Both pools with slot ``b``'s new int8 K/V row (B, Hkv, D) written at
+    ``pages[blk[b], :, off[b], :]``.
+
+    The Pallas path writes the rows in place (aliased pools); the XLA
+    fallback is a scatter, which on TPU copies the whole pool.
+    """
+    return ops.paged_kv_append(k_pages, v_pages, blk, off, k_new, v_new,
+                               impl=spec.impl)
+
+
 def paged_verify_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, block_table: jax.Array,
                            s_k: jax.Array, s_v: jax.Array,

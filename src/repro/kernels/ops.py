@@ -29,6 +29,7 @@ from repro.kernels import autotune
 from repro.kernels import blocked as blocked_lib
 from repro.kernels import ref as ref_lib
 from repro.kernels.int8_matmul import int8_matmul_pallas
+from repro.kernels.paged_append import paged_kv_append_pallas
 from repro.kernels.pallas_compat import pallas_supported
 from repro.kernels.splitmax_attn import splitmax_attention_pallas
 from repro.kernels.splitmax_decode import (
@@ -286,6 +287,31 @@ def splitmax_decode_fused_paged(
         q, k_pages, v_pages, block_table, m_z, s_q, s_v, cache_len,
         exp_lut, recip_lut, cfg=cfg, window=window, lut_mode=lut_mode,
         exact_recip=exact_recip, interpret=(impl == "interpret"))
+
+
+def paged_kv_append(
+    k_pages: jax.Array, v_pages: jax.Array,
+    blk: jax.Array, off: jax.Array,
+    k_new: jax.Array, v_new: jax.Array,
+    *,
+    impl: str = "auto",
+) -> Tuple[jax.Array, jax.Array]:
+    """Write slot ``b``'s new int8 K/V row (B, Hkv, D) at
+    ``pages[blk[b], :, off[b], :]`` of both (num_blocks, Hkv, block_k, D)
+    pools.
+
+    The Pallas path updates the pools in place (aliased operands, one
+    8-row group per slot read and written back); the ref/XLA fallbacks are
+    the scatter, which on TPU makes XLA copy the whole pool.  Rows of slots
+    that share a block (retired slots on the trash block) land in no
+    defined order.
+    """
+    impl = _resolve(impl)
+    if impl in ("ref", "xla"):
+        return (k_pages.at[blk, :, off, :].set(k_new),
+                v_pages.at[blk, :, off, :].set(v_new))
+    return paged_kv_append_pallas(k_pages, v_pages, blk, off, k_new, v_new,
+                                  interpret=(impl == "interpret"))
 
 
 # ---------------------------------------------------------------------------

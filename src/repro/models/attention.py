@@ -169,9 +169,9 @@ def attn_block_decode_paged(params, x, layer_cache: Dict, cfg: ModelConfig, *,
 
     ``layer_cache``: k_pages/v_pages (num_blocks, Hkv, block_k, hd), scalar
     scales, block_table (B, max_blocks), length (B,).  The new token's K/V
-    are quantized with the static scales and scattered into the slot's
-    *current tail block* (table[b, pos // block_k]); retired slots point at
-    the trash block, so their writes are harmless.
+    are quantized with the static scales and written, in place, into the
+    slot's *current tail block* (table[b, pos // block_k]); retired slots
+    point at the trash block, so their writes are harmless.
     """
     b = x.shape[0]
     dt = cfg.compute_dtype
@@ -193,8 +193,9 @@ def attn_block_decode_paged(params, x, layer_cache: Dict, cfg: ModelConfig, *,
     b_idx = jnp.arange(b)
     blk = table[b_idx, pos // block_k]             # (B,) pool block ids
     off = pos % block_k
-    k_pages = layer_cache["k_pages"].at[blk, :, off, :].set(k_new)
-    v_pages = layer_cache["v_pages"].at[blk, :, off, :].set(v_new)
+    k_pages, v_pages = core_attn.paged_append(
+        layer_cache["k_pages"], layer_cache["v_pages"], blk, off, k_new,
+        v_new, spec)
     out = core_attn.paged_decode_attention(
         q[:, :, 0, :], k_pages, v_pages, table, s_k, s_v, new_len, spec)
     out = out.reshape(b, 1, cfg.n_heads * hd)

@@ -577,25 +577,35 @@ def _decode_segment(seg_params, x, cfg, kind, n, offset, cache):
         kvc = cache["kv"]
         sl = slice(offset, offset + n)
         if "k_pages" in kvc:                       # paged block pool
+            # The whole (L, N, ...) pools ride in the carry, and layer l
+            # sees them as one (L*N, ...) pool whose block ids are offset by
+            # l*N: the reshape is free and the append writes in place, so no
+            # layer's pages are sliced out or copied back.  Layer l's own
+            # block 0 is its trash block.
+            shape = kvc["k_pages"].shape
+            nb = shape[1]
+            flat = (shape[0] * nb,) + shape[2:]
 
-            def body(x, xs):
-                layer_params, kp, vp, s_k, s_v = xs
+            def body(carry, xs):
+                x, kp, vp = carry
+                layer_params, s_k, s_v, layer = xs
                 slice_ = {"kv": {"k_pages": kp, "v_pages": vp,
                                  "scale_k": s_k, "scale_v": s_v,
-                                 "block_table": kvc["block_table"],
+                                 "block_table": kvc["block_table"]
+                                 + layer * nb,
                                  "length": kvc["length"]}}
                 x, new_slice = _block_decode_paged(layer_params, x, slice_,
                                                    cfg, kind)
                 nkv = new_slice["kv"]
-                return x, (nkv["k_pages"], nkv["v_pages"])
+                return (x, nkv["k_pages"], nkv["v_pages"]), None
 
-            x, (kp, vp) = maybe_scan(
-                body, x, (seg_params, kvc["k_pages"][sl], kvc["v_pages"][sl],
-                          kvc["scale_k"][sl], kvc["scale_v"][sl]), cfg)
-            cache = dict(cache, kv=dict(
-                kvc,
-                k_pages=kvc["k_pages"].at[sl].set(kp),
-                v_pages=kvc["v_pages"].at[sl].set(vp)))
+            (x, kp, vp), _ = maybe_scan(
+                body, (x, kvc["k_pages"].reshape(flat),
+                       kvc["v_pages"].reshape(flat)),
+                (seg_params, kvc["scale_k"][sl], kvc["scale_v"][sl],
+                 jnp.arange(offset, offset + n)), cfg)
+            cache = dict(cache, kv=dict(kvc, k_pages=kp.reshape(shape),
+                                        v_pages=vp.reshape(shape)))
             return x, cache
 
         def body(x, xs):

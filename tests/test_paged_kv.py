@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_arch
+from repro.core import attention as core_attn
 from repro.core import paged_kv
 from repro.core import split_softmax as ss
 from repro.core.lut import LUTConfig
@@ -329,6 +330,120 @@ def test_paged_decode_bit_matches_dense(rng):
         np.testing.assert_array_equal(np.asarray(log_d), np.asarray(log_p))
         tok_d = jnp.argmax(log_d, -1).astype(jnp.int32)
         tok_p = jnp.argmax(log_p, -1).astype(jnp.int32)
+
+
+# ----------------------- kernel: in-place K/V append ------------------------
+
+APPEND_GRID = [
+    # b, hkv, bk, d
+    (8, 4, 32, 64),
+    (6, 2, 8, 128),
+    (7, 16, 32, 128),           # OLMo-1B's K/V widths, an odd slot count
+]
+
+
+@pytest.mark.parametrize("shape", APPEND_GRID)
+def test_paged_kv_append_matches_scatter(rng, shape):
+    """The aliased append kernel (interpret mode) writes every live slot's
+    row where the XLA scatter does and leaves every other row as it was:
+    offsets 0 and block_k - 1 among random ones, retired slots on the trash
+    block, every non-trash page compared."""
+    b, hkv, bk, d = shape
+    num_blocks = 1 + 3 * b
+    k_pages = jnp.asarray(rng.integers(-128, 128, (num_blocks, hkv, bk, d)),
+                          jnp.int8)
+    v_pages = jnp.asarray(rng.integers(-128, 128, (num_blocks, hkv, bk, d)),
+                          jnp.int8)
+    blk = rng.permutation(np.arange(1, num_blocks))[:b]
+    blk[1::3] = paged_kv.TRASH_BLOCK                 # retired slots
+    off = rng.integers(0, bk, (b,))
+    off[0], off[2] = 0, bk - 1
+    k_new = jnp.asarray(rng.integers(-128, 128, (b, hkv, d)), jnp.int8)
+    v_new = jnp.asarray(rng.integers(-128, 128, (b, hkv, d)), jnp.int8)
+    args = (k_pages, v_pages, jnp.asarray(blk, jnp.int32),
+            jnp.asarray(off, jnp.int32), k_new, v_new)
+    got = ops.paged_kv_append(*args, impl="interpret")
+    want = ops.paged_kv_append(*args, impl="xla")
+    for g, w, name in zip(got, want, ("k", "v")):
+        np.testing.assert_array_equal(np.asarray(g)[1:], np.asarray(w)[1:],
+                                      err_msg=name)
+
+
+def _scatter_append(k_pages, v_pages, blk, off, k_new, v_new, spec):
+    return (k_pages.at[blk, :, off, :].set(k_new),
+            v_pages.at[blk, :, off, :].set(v_new))
+
+
+def _decode_step_sliced(params, token, cfg, cache):
+    """The paged decode step as it was before the pool rode in the layer
+    scan's carry: each layer's pages are sliced out, scanned as inputs,
+    returned as outputs and set back, and each token's K/V row goes in by
+    an XLA scatter (patched in while this function is traced)."""
+    x = T.embed_tokens(params, token[:, None], cfg)
+    kvc = cache["kv"]
+    offset = 0
+    for seg_params, (kind, n) in zip(params["segments"], T._layer_kinds(cfg)):
+        sl = slice(offset, offset + n)
+
+        def body(x, xs):
+            layer_params, kp, vp, s_k, s_v = xs
+            slice_ = {"kv": dict(kvc, k_pages=kp, v_pages=vp, scale_k=s_k,
+                                 scale_v=s_v)}
+            x, new = T._block_decode_paged(layer_params, x, slice_, cfg, kind)
+            return x, (new["kv"]["k_pages"], new["kv"]["v_pages"])
+
+        x, (kp, vp) = T.maybe_scan(
+            body, x, (seg_params, kvc["k_pages"][sl], kvc["v_pages"][sl],
+                      kvc["scale_k"][sl], kvc["scale_v"][sl]), cfg)
+        kvc = dict(kvc, k_pages=kvc["k_pages"].at[sl].set(kp),
+                   v_pages=kvc["v_pages"].at[sl].set(vp))
+        offset += n
+    kvc = dict(kvc, length=kvc["length"] + 1)
+    cache = dict(cache, kv=kvc, length=cache["length"] + 1)
+    return T.unembed(params, x, cfg)[:, 0], cache
+
+
+@pytest.mark.parametrize("impl,scan", [("xla", True), ("interpret", True),
+                                       ("xla", False)])
+def test_decode_step_in_place_matches_sliced_layers(rng, impl, scan,
+                                                    monkeypatch):
+    """Several steps of the paged decode step, which carries the whole pool
+    through the layer scan, hands each layer the flattened pool with an
+    offset block table and appends each row with the aliased kernel,
+    against the sliced-per-layer formulation with the scatter append:
+    logits and both pools bitwise equal, a retired slot and a page boundary
+    included."""
+    cfg = _smoke_cfg().replace(attn_impl=impl, scan_layers=scan)
+    params = st.init_params_fn(cfg)(jax.random.PRNGKey(3))
+    block_k, max_len, slots = 8, 32, 3
+    bps = paged_kv.blocks_per_seq(max_len, block_k)
+    cache = T.make_paged_cache(cfg, slots, max_len, block_k=block_k)
+    tok = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 6)), jnp.int32)
+    # slots 0 and 2 hold requests; slot 1 is retired on the trash block
+    _, cache = T.prefill_paged(
+        params, tok, cfg, cache, jnp.asarray([0, 2], jnp.int32),
+        jnp.asarray([np.arange(1, 1 + bps), np.arange(1 + 2 * bps,
+                                                      1 + 3 * bps)],
+                    jnp.int32), calibrate=True)
+    ref = jax.tree.map(lambda a: a, cache)
+    step = jax.jit(lambda p, t, c: T.decode_step(p, t, cfg, c))
+
+    def sliced_fn(p, t, c):
+        with monkeypatch.context() as m:
+            m.setattr(core_attn, "paged_append", _scatter_append)
+            return _decode_step_sliced(p, t, cfg, c)
+
+    sliced = jax.jit(sliced_fn)
+    token = jnp.asarray(rng.integers(0, cfg.vocab_size, (slots,)), jnp.int32)
+    for _ in range(4):                    # positions 6..9 cross a page
+        logits, cache = step(params, token, cache)
+        want, ref = sliced(params, token, ref)
+        np.testing.assert_array_equal(np.asarray(logits), np.asarray(want))
+        for name in ("k_pages", "v_pages", "length"):
+            np.testing.assert_array_equal(np.asarray(cache["kv"][name]),
+                                          np.asarray(ref["kv"][name]),
+                                          err_msg=name)
+        token = jnp.argmax(logits, -1).astype(jnp.int32)
 
 
 # --------------------------- scheduler: serve -------------------------------

@@ -161,3 +161,78 @@ def test_int8_matmul_compiles(one_chip, requant):
                                impl="pallas")
     _compile(gemm, "int8_matmul_pallas", one_chip, ((512, 2048), jnp.int8),
              ((2048, 2048), jnp.int8), SCALAR)
+
+
+@pytest.mark.parametrize("shape", list(PAGED_SHAPES))
+def test_paged_kv_append_compiles(one_chip, shape):
+    b, _, hkv, d, num_blocks, _ = PAGED_SHAPES[shape]
+    pool = ((num_blocks, hkv, BLOCK_K, d), jnp.int8)
+    rows = ((b, hkv, d), jnp.int8)
+    _compile(lambda *a: ops.paged_kv_append(*a, impl="pallas"),
+             "paged_kv_append_pallas", one_chip, pool, pool,
+             ((b,), jnp.int32), ((b,), jnp.int32), rows, rows)
+
+
+# ops that only pass a pool through the decode step: the donated
+# parameters, views of them, the layer loop that carries them, and the
+# Pallas kernels, which take and give them in place
+POOL_CARRIERS = {"parameter", "get-tuple-element", "bitcast", "tuple",
+                 "while", "custom-call"}
+
+
+def test_paged_decode_step_updates_pool_in_place(one_chip):
+    """The jitted, cache-donating paged decode step at OLMo-1B's K/V widths
+    (16 KV heads of 128, 32 slots x 2,048) never materialises a layer's
+    pages: no op but those that carry the pools has a pool-sized result,
+    the append and decode kernels are emitted under their names, and both
+    pools stay aliased from the donated cache to the returned one."""
+    from repro.configs import get_arch
+    from repro.launch import steps as st
+    from repro.models import transformer as T
+
+    n_layers, slots, max_len = 4, 32, 2048
+    cfg = get_arch("olmo_1b").config.replace(
+        n_layers=n_layers, d_model=256, d_ff=512, vocab_size=512,
+        attn_impl="pallas")
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(st.init_params_fn(cfg),
+                                      jax.random.PRNGKey(0)))
+    cache = described(jax.eval_shape(
+        lambda: T.make_paged_cache(cfg, slots, max_len, block_k=BLOCK_K)))
+    token = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    assert cache["kv"]["k_pages"].shape[2:] == (16, BLOCK_K, 128)
+    step = jax.jit(st.make_decode_step(cfg), donate_argnums=(2,),
+                   compiler_options=st.EXACT_ROUNDING)
+    text = step.lower(params, token, cache).compile().as_text()
+
+    nb = cache["kv"]["k_pages"].shape[1]
+    pool_sized = re.compile(rf"s8\[[^\]]*\b({nb}|{n_layers * nb})\b")
+    instr = re.compile(r"\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][a-z\-]*)\(")
+    strays, kernels = [], set()
+    for line in text.splitlines():
+        m = instr.match(line)
+        if not m or not pool_sized.search(m.group(2)):
+            continue
+        name, op = m.group(1), m.group(3)
+        if op not in POOL_CARRIERS or (
+                op == "custom-call" and "tpu_custom_call" not in line):
+            strays.append(f"{op} {name}")
+        if op == "custom-call":
+            kernels.add(re.sub(r"\.\d+$", "", name))
+    assert not strays, strays
+    assert kernels == {"paged_kv_append_pallas"}, kernels
+    for kernel in ("paged_kv_append_pallas",
+                   "splitmax_decode_fused_paged_pallas"):
+        assert re.search(rf"%{kernel}(\.\d+)? = .*tpu_custom_call", text), \
+            kernel
+
+    params_of = {m.group(1): int(m.group(2)) for m in re.finditer(
+        r"%cache__kv____(\w_pages)__\S* = s8\S* parameter\((\d+)\)", text)}
+    assert sorted(params_of) == ["k_pages", "v_pages"], params_of
+    header = text.splitlines()[0]
+    aliased = {int(p) for p in re.findall(r"\{\d*\}: \((\d+), \{\}", header)}
+    assert set(params_of.values()) <= aliased, (params_of, header[:400])
